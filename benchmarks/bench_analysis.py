@@ -105,7 +105,8 @@ def _differential(emit, samples, verify_samples):
                 break
     for name in VERIFY_MATRIX:
         rep = run_differential(
-            _workload(name), PallasBackend(scale=0.02, verify=True),
+            _workload(name), PallasBackend(scale=0.02, verify=True,
+                                            interpret=True),
             samples=verify_samples, seed=SEED + 1, label="pallas-verify")
         reports.append(rep)
         per_workload[name] = per_workload.get(name, 0) + rep.samples

@@ -38,7 +38,7 @@ import time
 from repro.core import (CostModelBackend, FaultInjectingBackend, GEMM,
                         SearchSpace, TuningSession, TuningSpec)
 
-from .common import save_result
+from .common import cli_env, save_result
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -114,14 +114,6 @@ def _scaling(emit):
     }, ok
 
 
-def _cli_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    env.pop("CC_RESULT_STORE", None)
-    return env
-
-
 def _kill9_resume_async(emit):
     # random search: the trajectory is completion-order independent, so
     # the resumed async run must reproduce the reference log byte for byte
@@ -146,14 +138,14 @@ def _kill9_resume_async(emit):
 
         ref = subprocess.run(cmd + ["--out", ref_path, "--checkpoint",
                                     os.path.join(tmp, "ref_ck.pkl")],
-                             cwd=REPO, env=_cli_env(), capture_output=True,
+                             cwd=REPO, env=cli_env(), capture_output=True,
                              text=True, timeout=600)
         if ref.returncode != 0:
             emit(f"  kill9-async: reference run failed: {ref.stderr.strip()}")
             return {"reference_exit": ref.returncode}, False
 
         victim = subprocess.Popen(cmd + ["--out", os.path.join(tmp, "x.json")],
-                                  cwd=REPO, env=_cli_env(),
+                                  cwd=REPO, env=cli_env(),
                                   stdout=subprocess.DEVNULL,
                                   stderr=subprocess.DEVNULL)
         deadline = time.time() + 120
@@ -168,7 +160,7 @@ def _kill9_resume_async(emit):
              f"(rc={victim.returncode})")
 
         res = subprocess.run(cmd + ["--out", res_path, "--resume"],
-                             cwd=REPO, env=_cli_env(), capture_output=True,
+                             cwd=REPO, env=cli_env(), capture_output=True,
                              text=True, timeout=600)
         ok = res.returncode == 0 and os.path.exists(res_path)
         identical = False

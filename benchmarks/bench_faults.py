@@ -32,7 +32,7 @@ import time
 from repro.core import (CostModelBackend, FaultInjectingBackend, GEMM,
                         RetryPolicy, SearchSpace, TuningSession, TuningSpec)
 
-from .common import first_reaching, save_result
+from .common import cli_env, first_reaching, save_result
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -88,14 +88,6 @@ def _fault_vs_clean(emit):
     }, best_match and within_2x and wall_bounded and injected > 0
 
 
-def _cli_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    env.pop("CC_RESULT_STORE", None)
-    return env
-
-
 def _kill9_resume(emit):
     # slow-only injection stretches the run (a kill window exists) without
     # perturbing any result, so the resumed trajectory must be byte-identical
@@ -119,14 +111,14 @@ def _kill9_resume(emit):
 
         ref = subprocess.run(cmd + ["--out", ref_path, "--checkpoint",
                                     os.path.join(tmp, "ref_ck.pkl")],
-                             cwd=REPO, env=_cli_env(), capture_output=True,
+                             cwd=REPO, env=cli_env(), capture_output=True,
                              text=True, timeout=600)
         if ref.returncode != 0:
             emit(f"  kill9: reference run failed: {ref.stderr.strip()}")
             return {"reference_exit": ref.returncode}, False
 
         victim = subprocess.Popen(cmd + ["--out", os.path.join(tmp, "x.json")],
-                                  cwd=REPO, env=_cli_env(),
+                                  cwd=REPO, env=cli_env(),
                                   stdout=subprocess.DEVNULL,
                                   stderr=subprocess.DEVNULL)
         deadline = time.time() + 120
@@ -141,7 +133,7 @@ def _kill9_resume(emit):
              f"(rc={victim.returncode})")
 
         res = subprocess.run(cmd + ["--out", res_path, "--resume"],
-                             cwd=REPO, env=_cli_env(), capture_output=True,
+                             cwd=REPO, env=cli_env(), capture_output=True,
                              text=True, timeout=600)
         ok = res.returncode == 0 and os.path.exists(res_path)
         identical = False

@@ -50,7 +50,7 @@ from repro.core.session import TuningSpec
 from repro.fleet import Dispatcher, FleetHTTPServer
 from repro.fleet.protocol import http_json
 
-from .common import save_result
+from .common import cli_env, save_result
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -84,14 +84,6 @@ def _spec_doc(seed: int, *, budget: int = BUDGET, slow_s: float = SLOW_S,
     return doc
 
 
-def _cli_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    env.pop("CC_RESULT_STORE", None)    # the gates must measure cold
-    return env
-
-
 class _Fleet:
     """Dispatcher + HTTP server in-process, worker subprocesses out."""
 
@@ -115,7 +107,7 @@ class _Fleet:
                  "--workdir", os.path.join(tmp, name),
                  "--poll-interval", "0.05",
                  "--heartbeat-interval", "0.25"],
-                cwd=REPO, env=_cli_env(),
+                cwd=REPO, env=cli_env(),
                 stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
 
     def status(self) -> dict:

@@ -48,7 +48,7 @@ def _full_extent_check(w, nest, args, want):
 def main(emit=print):
     emit("\n=== kernel-tuning gate (KernelWorkload through TuningSession) "
          "===")
-    backend = PallasBackend(scale=0.25, max_workers=4)
+    backend = PallasBackend(scale=0.25, max_workers=4, interpret=True)
     session = TuningSession(backend, store=False)   # the gate measures cold
     schedules: dict = {}
     rows: list[str] = []

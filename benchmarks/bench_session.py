@@ -28,7 +28,7 @@ import time
 from repro.core import GEMM, CostModelBackend, SearchSpace, TuningSpec
 from repro.core.strategies import run_greedy
 
-from .common import save_result
+from .common import cli_env, save_result
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -45,15 +45,12 @@ def main(emit=print):
         log_path = os.path.join(tmp, "log.json")
         spec.save(spec_path)
 
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.path.join(REPO, "src") + (
-            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        env.pop("CC_RESULT_STORE", None)    # the gate must measure cold
         t0 = time.time()
         proc = subprocess.run(
             [sys.executable, "-m", "repro.core.session", spec_path,
              "--out", log_path],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
+            cwd=REPO, env=cli_env(), capture_output=True, text=True,
+            timeout=600,
         )
         cli_seconds = time.time() - t0
         emit(f"  CLI: exit={proc.returncode} in {cli_seconds:.1f}s "

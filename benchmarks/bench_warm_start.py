@@ -34,6 +34,8 @@ import sys
 import tempfile
 import time
 
+from .common import REPO, cli_env
+
 WALL_BUDGET = 36
 WALL_SCALE = 0.1
 WALL_REPS = 2
@@ -72,16 +74,11 @@ def _child(workload_name: str, store_path: str, budget: int,
 
 
 def _run_child(workload_name: str, store_path: str) -> dict:
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    src = os.path.join(repo, "src")
-    env["PYTHONPATH"] = src + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    env.pop("CC_RESULT_STORE", None)   # the store under test is passed explicitly
+    # the store under test is passed explicitly
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_warm_start", "--child",
          workload_name, store_path, str(WALL_BUDGET), str(WALL_SCALE)],
-        cwd=repo, env=env, capture_output=True, text=True, timeout=900,
+        cwd=REPO, env=cli_env(), capture_output=True, text=True, timeout=900,
     )
     for line in proc.stdout.splitlines():
         if line.startswith(_CHILD_MARK):

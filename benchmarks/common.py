@@ -8,6 +8,20 @@ import pathlib
 import time
 
 RESULTS = pathlib.Path(__file__).parent / "results"
+REPO = pathlib.Path(__file__).resolve().parent.parent
+
+
+def cli_env() -> dict:
+    """Environment for a benchmark's child processes: ``src`` importable,
+    no ambient result store (the gates measure cold), and JAX held to the
+    CPU.  The children measure cost models or XLA:CPU; a TPU belongs to one
+    process, and the parent may already hold it."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(REPO / "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env.pop("CC_RESULT_STORE", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
 
 
 def results_dir() -> pathlib.Path:

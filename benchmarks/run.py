@@ -131,6 +131,9 @@ def main(argv=None) -> None:
              "newest record per key, conflict counters printed) and exit")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
+    compile_cache.enable()
+
     if args.json:
         d = os.path.dirname(args.json) or "."
         if not os.path.isdir(d):

@@ -62,7 +62,7 @@ def main():
           "already; the pragmas matter on the TPU path)")
 
     # correctness gate: the same schedule as a Pallas kernel vs the oracle
-    pb = PallasBackend(verify=True)
+    pb = PallasBackend(verify=True, interpret=True)
     res = pb.evaluate(GEMM, best.config)
     print(f"\npallas interpret-mode verification: {res.status} "
           f"(tpu-v5e cost-model projection {res.time_s:.4f}s)"

@@ -11,6 +11,7 @@ for real hardware.
 
 import argparse
 import dataclasses
+import pathlib
 import shutil
 
 from repro.configs.base import get_config
@@ -24,7 +25,8 @@ from repro.train.train_loop import LoopConfig, train
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
-    ap.add_argument("--ckpt-dir", type=str, default="/tmp/repro_train_lm")
+    ap.add_argument("--ckpt-dir", type=str, default=str(
+        pathlib.Path(__file__).resolve().parents[1] / "runs" / "train_lm"))
     ap.add_argument("--fail-at", type=int, default=30,
                     help="inject a simulated node failure at this step")
     ap.add_argument("--hundred-m", action="store_true",

@@ -96,6 +96,7 @@ class BackendModel:
     scale: float = 1.0
     vmem_limit: int | None = None
     verify: bool = False
+    interpret: bool = False         # pallas: scaled interpret-mode verify
 
     @classmethod
     def of(cls, backend) -> "BackendModel":
@@ -115,6 +116,7 @@ class BackendModel:
                 scale=getattr(b, "scale", 0.05),
                 vmem_limit=getattr(b, "vmem_limit", 128 * 1024 * 1024),
                 verify=getattr(b, "verify", True),
+                interpret=getattr(b, "interpret", False),
             )
         return cls(kind="generic")
 
@@ -281,6 +283,15 @@ def _xla(ctx: AnalysisContext) -> Iterable[Finding]:
         )
 
 
+def _verified(w, nest, model: BackendModel):
+    """The (workload, nest, scale) ``PallasBackend`` verifies: reduced and
+    retiled in interpret mode, the full schedule under Mosaic."""
+    if model.interpret:
+        ws = w.scaled(model.scale)
+        return ws, _retile_to(nest, ws), model.scale
+    return w, nest, 1.0
+
+
 @register_pass("feasibility.pallas")
 def _pallas(ctx: AnalysisContext) -> Iterable[Finding]:
     """Mirror of ``PallasBackend._measure`` for einsum workloads: plan
@@ -304,24 +315,23 @@ def _pallas(ctx: AnalysisContext) -> Iterable[Finding]:
         )
         return
     if model.verify:
-        ws = w.scaled(model.scale)
-        nest_small = _retile_to(nest, ws)
+        ws, nest_v, scale = _verified(w, nest, model)
         try:
-            plan = codegen._extract_plan(ws, nest_small)
+            plan = codegen._extract_plan(ws, nest_v)
             for v, _trips, span in plan.grid:
                 if span % plan.tile[v] != 0:
                     yield Finding(
                         rule="feasibility.pallas", status="compile_error",
                         detail=(f"var {v!r}: floor span {span} not a multiple "
                                 f"of its block width {plan.tile[v]} at "
-                                f"verification scale {model.scale}"),
+                                f"verification scale {scale}"),
                         evidence=(v, span, plan.tile[v]),
                     )
                     return
         except codegen.CodegenError as e:
             yield Finding(
                 rule="feasibility.pallas", status="compile_error",
-                detail=f"at verification scale {model.scale}: {e}",
+                detail=f"at verification scale {scale}: {e}",
                 evidence=("verify-plan",),
             )
 
@@ -349,14 +359,13 @@ def _kernel(ctx: AnalysisContext) -> Iterable[Finding]:
         )
         return
     if model.verify:
-        ws = w.scaled(model.scale)
-        nest_small = _retile_to(nest, ws)
+        ws, nest_v, scale = _verified(w, nest, model)
         try:
-            ws.kernel_params(nest_small)
+            ws.kernel_params(nest_v)
         except codegen.CodegenError as e:
             yield Finding(
                 rule="feasibility.kernel", status="compile_error",
-                detail=f"at verification scale {model.scale}: {e}",
+                detail=f"at verification scale {scale}: {e}",
                 evidence=("verify-blocks",),
             )
 

@@ -9,9 +9,8 @@ transformation sequence.  Two backends:
 * :func:`build_pallas` — a Pallas TPU kernel: the point band becomes the
   ``BlockSpec`` block shapes (VMEM tiles), floor loops become the grid in
   schedule order, reduction grid dims accumulate through a VMEM scratch
-  accumulator.  Validated with ``interpret=True`` on CPU; on real TPU the same
-  code lowers to Mosaic with ``dimension_semantics`` marking parallelized grid
-  dims.
+  accumulator.  ``interpret=True`` runs it in the Pallas interpreter on any
+  backend; ``interpret=False`` compiles it with Mosaic on a TPU.
 
 Multi-level (stacked) tilings — the paper's missed goal — lower exactly in
 both backends via per-loop element spans.  Structures that cannot be expressed
@@ -102,19 +101,24 @@ def _tile_einsum(w: Workload, tiles: dict[str, jnp.ndarray]) -> jnp.ndarray:
     acc = None
     for t in w.terms:
         subs = ",".join("".join(lt[v] for v in vs) for _, vs in t.accesses)
+        # f32 operands at f32 precision: Mosaic's default contracts them
+        # in one bf16 pass
         r = jnp.einsum(
             f"{subs}->{out_sub}",
             *[tiles[(arr, vs)] for arr, vs in t.accesses],
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
         acc = r if acc is None else acc + r
     return acc
 
 
-def _padded(arr: np.ndarray, vs: tuple[str, ...], covered: dict[str, int]):
+def _padded(arr, vs: tuple[str, ...], covered: dict[str, int]) -> jnp.ndarray:
+    """Zero-pad ``arr`` up to the covered extents (traceable under ``jit``)."""
+    arr = jnp.asarray(arr)
     pads = [(0, covered[v] - arr.shape[d]) for d, v in enumerate(vs)]
     if any(p[1] for p in pads):
-        return np.pad(arr, pads)
+        return jnp.pad(arr, pads)
     return arr
 
 
@@ -211,14 +215,16 @@ def build_xla(w: Workload, nest: LoopNest):
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU backend (BlockSpec tiling; interpret=True on this container)
+# Pallas TPU backend (BlockSpec tiling; Mosaic on a TPU, or interpret mode)
 # ---------------------------------------------------------------------------
 
 
-def build_pallas(w: Workload, nest: LoopNest, interpret: bool = True):
+def build_pallas(w: Workload, nest: LoopNest, *, interpret: bool):
     """Pallas kernel for the schedule.  Floor loops → grid (schedule order,
     last dim iterates fastest as on TPU); point band → BlockSpec block shapes;
-    reduction grid dims accumulate via VMEM scratch."""
+    reduction grid dims accumulate via VMEM scratch.  The returned
+    ``fn(args)`` is traceable, so callers can lower and compile it apart
+    from running it."""
     from jax.experimental.pallas import tpu as pltpu
 
     plan = _extract_plan(w, nest)
@@ -326,7 +332,7 @@ def build_pallas(w: Workload, nest: LoopNest, interpret: bool = True):
     def fn(args: dict[str, jnp.ndarray]) -> jnp.ndarray:
         ins = []
         for arr, vs in acc_list:
-            ins.append(jnp.asarray(_padded(np.asarray(args[arr]), vs, plan.covered)))
+            ins.append(_padded(args[arr], vs, plan.covered))
         out = call(*ins)
         out = out[tuple(slice(0, ext[v]) for v in w.out_vars)]
         if w.tri_mode == "lower":

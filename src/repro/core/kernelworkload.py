@@ -20,8 +20,9 @@ Workload` — "any callable with a structure key":
   head dim, multi-level tiling, a reordered grid, unroll/vectorize) raise
   :class:`~repro.core.codegen.CodegenError` and become red nodes, exactly
   like the paper's compile failures;
-* ``build(nest)`` — a callable evaluating the kernel (interpret-mode Pallas)
-  under that schedule, verified against the :mod:`repro.kernels.ref` oracle.
+* ``build(nest, interpret=...)`` — a callable evaluating the kernel under
+  that schedule (Mosaic, or interpret-mode Pallas), verified against the
+  :mod:`repro.kernels.ref` oracle.
 
 Instances are pure data (kernel behavior lives in a name-keyed registry
 populated at import), so they pickle across the
@@ -153,9 +154,10 @@ class KernelWorkload:
         (red node)."""
         return _kernel_def(self.kernel).kernel_params(self, nest)
 
-    def build(self, nest: LoopNest, interpret: bool = True) -> Callable:
-        """Callable ``f(args) -> array`` running the kernel under the
-        schedule ``nest`` encodes."""
+    def build(self, nest: LoopNest, *, interpret: bool) -> Callable:
+        """Traceable callable ``f(args) -> array`` running the kernel under
+        the schedule ``nest`` encodes (Mosaic, or the Pallas interpreter
+        when ``interpret``)."""
         return _kernel_def(self.kernel).build(self, nest, interpret)
 
     def vmem_bytes(self, nest: LoopNest) -> int:

@@ -16,10 +16,10 @@ Backends:
   figures since this container has one CPU core.
 * :class:`WallclockBackend` — real execution of the XLA:CPU tiled codegen at a
   reduced problem scale; cross-checks the model's tiling/interchange rankings.
-* :class:`PallasBackend` — builds the Pallas kernel (interpret=True), verifies
-  it against the jnp oracle, and reports the TPU cost-model time; additionally
-  enforces the VMEM capacity limit (tiles too large → compile_error, exactly
-  what Mosaic would say on hardware).
+* :class:`PallasBackend` — compiles the Pallas kernel with Mosaic on a TPU
+  (or, when asked, runs it in the Pallas interpreter at a reduced scale),
+  verifies it against the jnp oracle, and reports the TPU cost-model time;
+  tiles over the VMEM limit and Mosaic's own refusals are ``compile_error``.
 
 Batching model
 --------------
@@ -1036,6 +1036,13 @@ class WallclockBackend(_SupervisedMeasureMixin, _ThreadedEvalMixin, Backend):
             return Result("exec_error", note=f"{type(e).__name__}: {e}")
 
 
+def _on_tpu() -> bool:
+    """True when JAX's default backend is a TPU (initialises the backend)."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 def _is_kernel_workload(w) -> bool:
     """A workload is "any callable with a structure key": anything exposing
     ``build``/``vmem_bytes`` (e.g. :class:`~repro.core.kernelworkload.
@@ -1046,28 +1053,38 @@ def _is_kernel_workload(w) -> bool:
 
 @dataclass
 class PallasBackend(_SupervisedMeasureMixin, _ThreadedEvalMixin, Backend):
-    """Builds the Pallas kernel (interpret mode), checks correctness against
-    the jnp oracle at a reduced scale, rejects VMEM-overflowing tiles, and
-    scores with the TPU cost model.  The reported time is deterministic (cost
-    model), so batched verification can run on a thread pool safely.
+    """Builds the Pallas kernel, checks it against the jnp oracle, rejects
+    VMEM-overflowing tiles, and scores with the TPU cost model.  The reported
+    time is the cost model's, never a device time; it is deterministic, so
+    batched verification can run on a thread pool safely.
+
+    Two verification modes, never mixed in one store scope:
+
+    * ``interpret=False`` (default) compiles every candidate with Mosaic and
+      runs it at the workload's full extents.  It needs a TPU and raises
+      without one.  A lowering or compile refusal is ``compile_error``; only
+      a failed run or an oracle mismatch is ``exec_error``.
+    * ``interpret=True`` runs the Pallas interpreter on any backend, at
+      ``scale`` of the extents (``_retile_to``) so that it stays fast.
 
     Workloads exposing their own ``build``/``vmem_bytes`` (kernel workloads
     — the repo's hand-written Pallas kernels wrapped as tunables) take those
-    in place of the einsum ``codegen`` path; everything else (scaled
-    verification, cost-model scoring, the supervised pool, the store scope)
-    is identical.
+    in place of the einsum ``codegen`` path; everything else is identical.
 
     ``timeout_s`` arms a *hard* per-kernel deadline: with
     ``process_workers>=1`` verification runs inside a :class:`SupervisedPool`
     worker that is SIGKILLed (and respawned) when one interpret-mode
     verification hangs past the deadline — the kernel becomes an
     ``exec_error("timeout ...")`` red node.  Without workers the thread path
-    cannot preempt, so ``timeout_s`` is only honored via the pool."""
+    cannot preempt, so ``timeout_s`` is only honored via the pool.  A TPU
+    belongs to one process, so on a TPU host ``process_workers>=1`` is
+    refused: the spawned workers could not open the chip."""
 
     machine: Machine = TPU_V5E
-    scale: float = 0.05
+    scale: float = 0.05                 # interpret-mode verification only
     vmem_limit: int = 128 * 1024 * 1024
     verify: bool = True
+    interpret: bool = False
     name: str = "pallas"
     max_workers: int = 4
     timeout_s: float | None = None      # hard kill deadline (needs workers)
@@ -1086,9 +1103,18 @@ class PallasBackend(_SupervisedMeasureMixin, _ThreadedEvalMixin, Backend):
     _warned_fallback: bool = field(
         default=False, init=False, repr=False, compare=False)
 
+    def __post_init__(self) -> None:
+        if self.process_workers >= 1 and _on_tpu():
+            raise ValueError(
+                "PallasBackend(process_workers>=1) on a TPU host: a chip "
+                "belongs to one process at a time, so spawned measurement "
+                "workers cannot open it. Measure in the process that holds "
+                "the chip (process_workers=0).")
+
     def worker_spec(self) -> dict:
         return {"machine": self.machine, "scale": self.scale,
-                "vmem_limit": self.vmem_limit, "verify": self.verify}
+                "vmem_limit": self.vmem_limit, "verify": self.verify,
+                "interpret": self.interpret}
 
     def _pool_deadline(self) -> float | None:
         return self.timeout_s
@@ -1121,8 +1147,11 @@ class PallasBackend(_SupervisedMeasureMixin, _ThreadedEvalMixin, Backend):
 
     def store_scope(self) -> str:
         # Reported time is the deterministic TPU cost model → host-independent;
-        # verification scale/vmem affect which configs are red.
-        return (f"pallas:{self.machine.name}:scale={self.scale}"
+        # the verification mode (and, interpreted, its scale) and the vmem
+        # limit decide which configs are red.
+        mode = (f"interpret:scale={self.scale}" if self.interpret
+                else "mosaic")
+        return (f"pallas:{mode}:{self.machine.name}"
                 f":vmem={self.vmem_limit}:verify={self.verify}")
 
     def _measure(self, workload: Workload, nest: LoopNest) -> Result:
@@ -1138,26 +1167,51 @@ class PallasBackend(_SupervisedMeasureMixin, _ThreadedEvalMixin, Backend):
         except codegen.CodegenError as e:
             return Result("compile_error", note=str(e))
         if self.verify:
-            w = workload.scaled(self.scale)
-            try:
-                nest_small = _retile_to(nest, w)
-                fn = (w.build(nest_small, interpret=True)
-                      if _is_kernel_workload(w)
-                      else codegen.build_pallas(w, nest_small, interpret=True))
-                args = w.make_args()
-                got = np.asarray(fn(args))
-                want = np.asarray(w.reference(args))
-                if not np.allclose(got, want, rtol=2e-4, atol=2e-4):
-                    return Result(
-                        "exec_error",
-                        note=f"pallas/oracle mismatch: max err "
-                        f"{float(np.abs(got - want).max()):.3e}",
-                    )
-            except codegen.CodegenError as e:
-                return Result("compile_error", note=str(e))
-            except Exception as e:  # noqa: BLE001
-                return Result("exec_error", note=f"{type(e).__name__}: {e}")
+            res = self._verify(workload, nest)
+            if res is not None:
+                return res
         return Result("ok", time_s=estimate_time(nest, self.machine))
+
+    def _verify(self, workload: Workload, nest: LoopNest) -> Result | None:
+        """Compile, run and check one candidate; a red :class:`Result`, or
+        ``None`` when the kernel matches the oracle."""
+        import jax
+
+        if self.interpret:
+            w = workload.scaled(self.scale)
+            nest = _retile_to(nest, w)
+        elif not _on_tpu():
+            raise RuntimeError(
+                f"PallasBackend: Mosaic compilation needs a TPU, and JAX's "
+                f"default backend is {jax.default_backend()!r}. Pass "
+                f"interpret=True to verify in the Pallas interpreter.")
+        else:
+            w = workload
+        try:
+            fn = (w.build(nest, interpret=self.interpret)
+                  if _is_kernel_workload(w)
+                  else codegen.build_pallas(w, nest,
+                                            interpret=self.interpret))
+        except codegen.CodegenError as e:
+            return Result("compile_error", note=str(e))
+        args = {k: jax.numpy.asarray(v) for k, v in w.make_args().items()}
+        try:
+            compiled = jax.jit(fn).lower(args).compile()
+        except Exception as e:  # noqa: BLE001 — any refusal is a red node
+            return Result("compile_error", note=f"{type(e).__name__}: {e}")
+        try:
+            got = np.asarray(compiled(args))
+        except Exception as e:  # noqa: BLE001
+            return Result("exec_error", note=f"{type(e).__name__}: {e}")
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(w.reference(args))
+        if not np.allclose(got, want, rtol=2e-4, atol=2e-4):
+            return Result(
+                "exec_error",
+                note=f"pallas/oracle mismatch: max err "
+                f"{float(np.abs(got - want).max()):.3e}",
+            )
+        return None
 
 
 # Built-in worker-backend builders (the "fault" kind registers itself on

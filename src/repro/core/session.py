@@ -869,6 +869,9 @@ def main(argv: Sequence[str] | None = None) -> int:
                     help="suppress the per-run summary line")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
+    compile_cache.enable()
+
     try:
         spec = TuningSpec.load(args.spec)
     except (OSError, ValueError, TypeError) as e:

@@ -27,6 +27,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                   *, scale, causal, block_q, block_kv, q_offset, kv_len):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    # f32 inputs contract at f32 precision: Mosaic's default for f32
+    # operands is one bf16 pass, ~1e-2 off an f32 reference
+    prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
+            else None)
 
     @pl.when(ki == 0)
     def _():
@@ -36,7 +40,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     q = q_ref[0].astype(jnp.float32) * scale         # (bq, d)
     k = k_ref[0].astype(jnp.float32)                 # (bkv, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # (bq, bkv)
+    s = jnp.dot(q, k.T, precision=prec,
+                preferred_element_type=jnp.float32)           # (bq, bkv)
 
     # ``kv_len`` is the true (unpadded) KV length; when the KV axis was
     # padded to a block multiple the tail columns must never win the softmax
@@ -59,7 +64,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     m_ref[...] = m_new
     v = v_ref[0].astype(jnp.float32)
     acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p, v, precision=prec, preferred_element_type=jnp.float32)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _():
@@ -75,7 +80,7 @@ def flash_attention(
     block_q: int = 512,
     block_kv: int = 512,
     scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape

@@ -42,7 +42,7 @@ def covariance(
     block_i: int = 256,
     block_j: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     k, m = data.shape
     bi, bj, bk = min(block_i, m), min(block_j, m), min(block_k, k)

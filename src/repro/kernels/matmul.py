@@ -44,7 +44,7 @@ def matmul(
     block_n: int = 256,
     block_k: int = 512,
     out_dtype: jnp.dtype | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """``x @ y`` with explicit VMEM tiling.  Shapes must divide the blocks
     (the ``ops`` wrapper pads); accumulation is f32."""

@@ -1,8 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 Each ``*_ref`` is the semantic ground truth the kernels are asserted against
-(interpret=True on CPU, real Mosaic on TPU).  They are deliberately written as
-straight-line jnp — no blocking, no tricks.
+(in interpret mode on CPU, compiled with Mosaic on a TPU).  They are
+deliberately written as straight-line jnp — no blocking, no tricks.
 """
 
 from __future__ import annotations

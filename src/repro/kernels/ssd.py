@@ -7,9 +7,17 @@ recurrence: the chunk length is a tile size trading intra-chunk matmul work
 why mamba2 is one of the §Perf hillclimb candidates.
 
 Kernel layout: grid = (batch·head, n_chunks) with the chunk dim sequential
-("arbitrary" semantics — it carries the (P, N) state in VMEM scratch).  Each
+("arbitrary" semantics — it carries the (N, P) state in VMEM scratch).  Each
 step does three MXU contractions (CBᵀ scores, score·x, state update) on
 (chunk × N/P) tiles.
+
+Every value in the kernel body is 2-D — per-step quantities are ``(chunk, 1)``
+columns or ``(1, chunk)`` rows — because Mosaic lowers neither ``cumsum`` nor
+the dynamic slices that 1-D vector indexing produces.  The inclusive prefix
+sum of the log-decay is a product with a triangular 0/1 matrix.  Every
+contraction of f32 values runs at ``HIGHEST`` precision: Mosaic's default
+contracts f32 operands in one bf16 pass, which the decays and an f32 caller
+cannot afford.
 """
 
 from __future__ import annotations
@@ -20,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref,
@@ -32,29 +42,46 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref,
 
     x = x_ref[0].astype(jnp.float32)        # (chunk, P)
     dt = dt_ref[0].astype(jnp.float32)      # (chunk, 1)
-    a = a_ref[0, 0]                         # scalar decay rate (negative)
+    a = a_ref[0].astype(jnp.float32)        # (1, 1) decay rate (negative)
     b = b_ref[0].astype(jnp.float32)        # (chunk, N)
     c = c_ref[0].astype(jnp.float32)        # (chunk, N)
 
-    la = dt[:, 0] * a                       # (chunk,) log-decay
-    cum = jnp.cumsum(la)                    # inclusive
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = s_idx <= t_idx                    # (t, s): s ≤ t
+    la = dt * a                             # (chunk, 1) log-decay
+    # the same values as a row: only the diagonal term of each column is
+    # nonzero, so the reduction is exact
+    la_row = jnp.sum(jnp.where(s_idx == t_idx, la, 0.0), axis=0,
+                     keepdims=True)         # (1, chunk)
+    dt_row = jnp.sum(jnp.where(s_idx == t_idx, dt, 0.0), axis=0,
+                     keepdims=True)         # (1, chunk)
+    # inclusive prefix sums: cum[t] = Σ_{s≤t} la[s], as a column and a row
+    cum = jnp.dot(tri.astype(jnp.float32), la, precision=_HI,
+                  preferred_element_type=jnp.float32)            # (chunk, 1)
+    cum_row = jnp.dot(la_row, (t_idx <= s_idx).astype(jnp.float32),
+                      precision=_HI,
+                      preferred_element_type=jnp.float32)        # (1, chunk)
+    total = jnp.dot(la_row, jnp.ones((chunk, 1), jnp.float32), precision=_HI,
+                    preferred_element_type=jnp.float32)          # (1, 1)
     # intra-chunk lower-triangular decay kernel (masked before exp — the
     # upper entries have positive exponents that overflow)
-    seg = cum[:, None] - cum[None, :]
-    tri = jnp.tril(jnp.ones((chunk, chunk), jnp.bool_))
-    decay = jnp.exp(jnp.where(tri, seg, -1e30))
-    scores = jnp.dot(c, b.T, preferred_element_type=jnp.float32) * decay
-    y = jnp.dot(scores * dt[:, 0][None, :], x,
+    decay = jnp.exp(jnp.where(tri, cum - cum_row, -1e30))
+    scores = jnp.dot(c, b.T, precision=_HI,
+                     preferred_element_type=jnp.float32) * decay
+    y = jnp.dot(scores * dt_row, x, precision=_HI,
                 preferred_element_type=jnp.float32)            # (chunk, P)
     # inter-chunk: incoming state contribution
     h = h_ref[...]                                             # (N, P)
-    y += jnp.exp(cum)[:, None] * jnp.dot(c, h,
-                                         preferred_element_type=jnp.float32)
+    y += jnp.exp(cum) * jnp.dot(c, h, precision=_HI,
+                                preferred_element_type=jnp.float32)
     # state update: h' = exp(total)·h + Σ_s exp(total-cum_s)·dt_s·b_s⊗x_s
-    total = cum[-1]
-    w = jnp.exp(total - cum) * dt[:, 0]                        # (chunk,)
-    h_ref[...] = jnp.exp(total) * h + jnp.dot(
-        (b * w[:, None]).T, x, preferred_element_type=jnp.float32)
+    w = jnp.exp(total - cum) * dt                              # (chunk, 1)
+    # (1, 1) → (1, P) first: Mosaic cannot broadcast over sublanes and
+    # lanes in one step
+    carry = jnp.exp(jnp.broadcast_to(total, (1, h.shape[1])))  # (1, P)
+    h_ref[...] = carry * h + jnp.dot(
+        (b * w).T, x, precision=_HI, preferred_element_type=jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
 
 
@@ -66,7 +93,7 @@ def ssd_scan(
     c: jnp.ndarray,          # (BH, L, N)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     BH, L, P = x.shape
     N = b.shape[-1]

@@ -45,7 +45,7 @@ def syr2k(
     block_i: int = 256,
     block_j: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     n, k = a.shape
     assert b.shape == (n, k)

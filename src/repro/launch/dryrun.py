@@ -42,7 +42,8 @@ from repro.models import sharding as sh
 from repro.models.model import build_model, count_params_from_specs, layer_groups
 from repro.optim import OptimizerConfig, init_opt_state
 from repro.roofline.analysis import RooflineReport, cost_summary, stitch
-from repro.train.steps import batch_axes, input_specs, make_train_step
+from repro.train.steps import (batch_axes, input_specs, make_train_step,
+                               state_shardings)
 
 
 def _axes_is_leaf(x):
@@ -135,12 +136,7 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str,
         if cell.kind == "train":
             ospecs = jax.eval_shape(
                 functools.partial(init_opt_state, opt_cfg), pspecs)
-            oshard = jax.tree.map(
-                lambda _: None, ospecs,
-                is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
-            # optimizer state inherits the param sharding leaf-by-leaf where
-            # shapes match; factored stats replicate their reduced dims
-            oshard = _opt_shardings(opt_cfg, pspecs, pshard, ospecs)
+            _, oshard = state_shardings(model, opt_cfg, pspecs)
             step = make_train_step(model, opt_cfg, microbatches=microbatches)
             jitted = jax.jit(step,
                              in_shardings=(pshard, oshard, bshard),
@@ -204,32 +200,6 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str,
               f"collective={rep.collective_s*1e3:.2f}ms dominant={rep.dominant} "
               f"roofline_frac={rep.roofline_fraction:.3f}")
     return rep.to_dict()
-
-
-def _opt_shardings(opt_cfg, pspecs, pshard, ospecs):
-    """Optimizer-state shardings: moments mirror the param sharding; factored
-    row/col stats and the step counter replicate."""
-    import jax.tree_util as jtu
-
-    pshard_flat = jtu.tree_leaves(
-        pshard, is_leaf=lambda x: x is None or hasattr(x, "spec"))
-    pspec_flat = jtu.tree_leaves(pspecs)
-
-    def mirror(tree):
-        leaves, treedef = jtu.tree_flatten(
-            tree, is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
-        out = []
-        for leaf in leaves:
-            match = None
-            for ps, psh in zip(pspec_flat, pshard_flat):
-                if ps.shape == leaf.shape:
-                    match = psh
-                    break
-            out.append(match)
-        return jtu.tree_unflatten(treedef, out)
-
-    from repro.optim.adamw import OptState
-    return OptState(step=None, m=mirror(ospecs.m), v=mirror(ospecs.v))
 
 
 def _lower_period_cost(model, cfg, cell, pspecs, g, chips):

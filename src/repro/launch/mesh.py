@@ -12,17 +12,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-
-def _axis_types_kwargs(n: int) -> dict:
-    """``axis_types=(Auto,)*n`` on jax versions that have it, ``{}`` on the
-    ones that don't (``jax.sharding.AxisType`` appeared after 0.4.x; older
-    meshes are implicitly Auto on every axis)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -31,14 +21,15 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — run "
             f"under launch/dryrun.py (it forces 512 host devices) or on a pod")
     # more devices than needed (e.g. 512 forced, single-pod 256 mesh): carve
     arr = np.asarray(devices[:n]).reshape(shape)
-    return Mesh(arr, axes, **_axis_types_kwargs(len(axes)))
+    return Mesh(arr, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def smoke_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -46,4 +37,4 @@ def smoke_mesh(data: int = 1, model: int = 1) -> Mesh:
     n = data * model
     devices = jax.devices()[:n]
     arr = np.asarray(devices).reshape((data, model))
-    return Mesh(arr, ("data", "model"), **_axis_types_kwargs(2))
+    return Mesh(arr, ("data", "model"), axis_types=(AxisType.Auto,) * 2)

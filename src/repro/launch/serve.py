@@ -75,7 +75,10 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-seq", type=int, default=256)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true", default=True,
+                    help="use the reduced (CPU-feasible) config")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="full config at its published widths")
     ap.add_argument("--ckpt", type=str, default=None,
                     help="checkpoint dir to restore params from")
     ap.add_argument("--tuned-schedules", type=str, default=None,
@@ -84,6 +87,9 @@ def main(argv=None):
                          "installs the tuned block sizes into the model "
                          "config (attn_q_chunk / ssd_chunk)")
     args = ap.parse_args(argv)
+
+    from repro import compile_cache
+    compile_cache.enable()
 
     import jax
     import numpy as np

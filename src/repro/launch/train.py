@@ -18,7 +18,9 @@ def main(argv=None):
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--global-batch", type=int, default=4)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--ckpt-dir", type=str, default="/tmp/repro_launch_train")
+    ap.add_argument("--ckpt-dir", type=str, required=True,
+                    help="checkpoint directory; a committed step found "
+                         "there is resumed")
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--reduced", action="store_true", default=True,
                     help="use the reduced (CPU-feasible) config")
@@ -27,6 +29,9 @@ def main(argv=None):
     ap.add_argument("--mesh", choices=["none", "single", "multi"],
                     default="none")
     args = ap.parse_args(argv)
+
+    from repro import compile_cache
+    compile_cache.enable()
 
     from repro.configs.base import get_config
     from repro.data.pipeline import DataConfig

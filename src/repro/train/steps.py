@@ -70,6 +70,37 @@ def make_train_step(model: Model, opt_cfg: OptimizerConfig,
     return train_step
 
 
+def state_shardings(model: Model, opt_cfg: OptimizerConfig, pspecs: Pytree):
+    """NamedShardings of ``(params, optimizer state)`` under the installed
+    sharding rules.  Each param follows its logical axes (an axis whose mesh
+    extent does not divide the dim replicates); each moment follows its
+    param; factored second-moment stats and the step counter replicate."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.optim.adamw import OptState
+
+    replicated = NamedSharding(sh.mesh(), PartitionSpec())
+    pshard = jax.tree.map(
+        lambda axes, s: sh.named_sharding_for(s.shape, *axes),
+        model.axes(), pspecs, is_leaf=_is_axes)
+    ospecs = jax.eval_shape(functools.partial(init_opt_state, opt_cfg), pspecs)
+
+    def mirror(psh, ps, o):
+        if isinstance(o, jax.ShapeDtypeStruct) and o.shape == ps.shape:
+            return psh
+        return jax.tree.map(lambda _: replicated, o)
+
+    return pshard, OptState(
+        step=replicated,
+        m=jax.tree.map(mirror, pshard, pspecs, ospecs.m),
+        v=jax.tree.map(mirror, pshard, pspecs, ospecs.v))
+
+
+def _is_axes(x) -> bool:
+    return isinstance(x, tuple) and all(a is None or isinstance(a, str)
+                                        for a in x)
+
+
 def make_serve_steps(model: Model):
     def prefill_step(params, batch):
         return model.prefill(params, batch)

@@ -16,6 +16,7 @@ from typing import Any, Callable
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import DataConfig, Prefetcher, host_batch
@@ -25,15 +26,15 @@ from repro.optim import OptimizerConfig, apply_updates, init_opt_state
 from repro.train import checkpoint as ckpt
 from repro.train.fault_tolerance import (FailureInjector, StragglerWatchdog,
                                          SimulatedFailure)
-from repro.train.steps import make_train_step
+from repro.train.steps import make_train_step, state_shardings
 
 
 @dataclass
 class LoopConfig:
+    ckpt_dir: str               # a committed checkpoint here is resumed
     total_steps: int = 200
     log_every: int = 10
     ckpt_every: int = 50
-    ckpt_dir: str = "/tmp/repro_ckpt"
     seed: int = 0
     microbatches: int = 1
     keep_ckpts: int = 3
@@ -45,6 +46,9 @@ class LoopResult:
     losses: list = field(default_factory=list)
     straggler_flags: list = field(default_factory=list)
     restored_from: int | None = None
+    # bytes of params + optimizer state each device holds, in jax.devices()
+    # order (a mesh shards them; without one device 0 holds everything)
+    device_state_bytes: list = field(default_factory=list)
 
 
 def train(cfg: ModelConfig, opt_cfg: OptimizerConfig, loop: LoopConfig,
@@ -60,22 +64,39 @@ def train(cfg: ModelConfig, opt_cfg: OptimizerConfig, loop: LoopConfig,
     step_fn = make_train_step(model, opt_cfg, microbatches=loop.microbatches)
 
     with sh.scope(mesh, rules) if mesh is not None else _nullcontext():
-        jitted = jax.jit(step_fn, donate_argnums=(0, 1))
+        key = jax.random.key(loop.seed)
 
-        params = model.init(jax.random.key(loop.seed))
-        opt_state = init_opt_state(opt_cfg, params)
+        def init_state(key):
+            params = model.init(key)
+            return params, init_opt_state(opt_cfg, params)
+
+        if mesh is None:
+            shardings = None
+            jitted = jax.jit(step_fn, donate_argnums=(0, 1))
+        else:
+            # params and moments are created already sharded: built on one
+            # device and resharded, they would not fit it at full width
+            shardings = state_shardings(
+                model, opt_cfg, jax.eval_shape(model.init, key))
+            jitted = jax.jit(
+                step_fn, donate_argnums=(0, 1),
+                out_shardings=shardings + (NamedSharding(mesh, P()),))
+        params, opt_state = jax.jit(init_state, out_shardings=shardings)(key)
         start_step = 0
         restored = None
         latest = ckpt.latest_step(loop.ckpt_dir)
         if latest is not None:
-            state = ckpt.restore(loop.ckpt_dir, latest, (params, opt_state))
+            state = ckpt.restore(loop.ckpt_dir, latest, (params, opt_state),
+                                 shardings=shardings)
             params, opt_state = state
             start_step = latest
             restored = latest
 
         saver = ckpt.AsyncCheckpointer(loop.ckpt_dir, keep=loop.keep_ckpts)
         watchdog = StragglerWatchdog()
-        result = LoopResult(last_step=start_step, restored_from=restored)
+        result = LoopResult(last_step=start_step, restored_from=restored,
+                            device_state_bytes=_device_bytes(
+                                (params, opt_state)))
 
         prefetch = Prefetcher(data_cfg, start_step=start_step)
         try:
@@ -103,6 +124,14 @@ def train(cfg: ModelConfig, opt_cfg: OptimizerConfig, loop: LoopConfig,
         finally:
             prefetch.close()
         return result
+
+
+def _device_bytes(tree) -> list[int]:
+    per = {d: 0 for d in jax.devices()}
+    for leaf in jax.tree_util.tree_leaves(tree):
+        for shard in leaf.addressable_shards:
+            per[shard.device] += shard.data.nbytes
+    return list(per.values())
 
 
 def _extend_batch(batch, cfg, data_cfg, step):

@@ -291,7 +291,8 @@ def test_kernel_workload_through_supervised_pool():
     a spawn worker (the registry repopulates on module import)."""
     w = attention_workload(seq_q=64, seq_kv=64, heads_q=4, heads_kv=2,
                            head_dim=16)
-    be = PallasBackend(scale=0.5, process_workers=1, timeout_s=120)
+    be = PallasBackend(scale=0.5, process_workers=1, timeout_s=120,
+                       interpret=True)
     try:
         cfgs = [Configuration(),
                 Configuration().child(Tile(loops=("q", "kv"),
@@ -301,3 +302,55 @@ def test_kernel_workload_through_supervised_pool():
         be.close()
     assert [r.status for r in out] == ["ok", "ok"]
     assert out[1].time_s <= out[0].time_s
+
+
+# ---------------------------------------------------------------------------
+# verification modes: Mosaic on a TPU, the interpreter only when asked for
+# ---------------------------------------------------------------------------
+
+
+def test_store_scope_separates_interpret_and_mosaic():
+    mosaic = PallasBackend().store_scope()
+    interp = PallasBackend(interpret=True).store_scope()
+    assert mosaic != interp
+    assert "mosaic" in mosaic and "scale" not in mosaic
+    assert "interpret" in interp and "scale=0.05" in interp
+    # the interpreter's scale changes what it verifies; Mosaic runs at full
+    # extents, so scale is not part of its scope
+    assert (PallasBackend(interpret=True, scale=0.5).store_scope()
+            != interp)
+    assert PallasBackend(scale=0.5).store_scope() == mosaic
+
+
+def test_mosaic_verification_off_tpu_raises_instead_of_red_nodes():
+    """Off a TPU a Mosaic backend must fail loudly, never quietly turn every
+    candidate red or fall back to the interpreter."""
+    a = attention_workload(seq_q=64, seq_kv=64, heads_q=4, heads_kv=2,
+                           head_dim=16)
+    with pytest.raises(RuntimeError, match="needs a TPU.*interpret=True"):
+        PallasBackend().evaluate(a, Configuration())
+    assert PallasBackend(interpret=True).evaluate(
+        a, Configuration()).status == "ok"
+
+
+def test_mosaic_refusal_is_compile_error(monkeypatch):
+    """A kernel Mosaic will not lower is a ``compile_error`` red node, not
+    an ``exec_error``: here the CPU backend's refusal of a non-interpret
+    Pallas call stands in for the chip compiler's."""
+    from repro.core import measure
+
+    monkeypatch.setattr(measure, "_on_tpu", lambda: True)
+    a = attention_workload(seq_q=64, seq_kv=64, heads_q=4, heads_kv=2,
+                           head_dim=16)
+    r = PallasBackend().evaluate(a, Configuration())
+    assert r.status == "compile_error" and "interpret" in r.note
+
+
+@pytest.mark.parametrize("kwargs", [{}, {"interpret": True}])
+def test_process_workers_refused_on_tpu_host(monkeypatch, kwargs):
+    from repro.core import measure
+
+    monkeypatch.setattr(measure, "_on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="one process"):
+        PallasBackend(process_workers=1, **kwargs)
+    PallasBackend(**kwargs)        # in-process measurement stays allowed

@@ -1,8 +1,7 @@
 """Regression tests for the serving-path launch/engine fixes:
 
-* ``launch/mesh.py`` must construct meshes on jax versions without
-  ``jax.sharding.AxisType`` (0.4.x) — the AttributeError previously broke
-  ``smoke_mesh`` and every checkpoint-restore test behind it.
+* ``launch/mesh.py`` must construct the smoke and production meshes on the
+  installed jax.
 * ``launch/hillclimb.py`` must append (not clobber) the forced-host-devices
   flag to a user-set ``XLA_FLAGS``, and must keep its module docstring.
 * ``serve/engine.py::_install_prefix`` must raise on an unmergeable prefill

@@ -111,3 +111,49 @@ def test_moe_distributed_matches_local(tmp_path):
     res = json.loads(out.stdout.strip().splitlines()[-1])
     # per-shard capacity changes which tokens drop → small tolerance
     assert abs(res["local"] - res["dist"]) < 0.05, res
+
+
+SHARDED_TRAIN_SCRIPT = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json, jax
+    from repro.configs.base import get_config
+    from repro.data.pipeline import DataConfig
+    from repro.launch.mesh import smoke_mesh
+    from repro.models import sharding as sh
+    from repro.optim import OptimizerConfig
+    from repro.train.train_loop import LoopConfig, train
+
+    cfg = get_config("internlm2_1_8b").reduced()
+    data = DataConfig(vocab_size=cfg.vocab_size, seq_len=32, global_batch=8)
+    opt = OptimizerConfig(lr=1e-3, warmup_steps=1, total_steps=2)
+    out = {}
+    for name, mesh in (("one", None), ("mesh", smoke_mesh(2, 2))):
+        loop = LoopConfig(ckpt_dir=os.path.join(sys.argv[1], name),
+                          total_steps=2, log_every=1, ckpt_every=100)
+        res = train(cfg, opt, loop, data, mesh=mesh,
+                    rules=dict(sh.DEFAULT_RULES) if mesh else None)
+        out[name] = {"losses": res.losses, "bytes": res.device_state_bytes}
+    print(json.dumps(out))
+""")
+
+
+def test_sharded_training_creates_state_sharded(tmp_path):
+    """On a (data 2, model 2) mesh the train loop creates params and
+    optimizer state already sharded — each device holds about a quarter,
+    not device 0 everything — and trains to the single-device losses."""
+    script = tmp_path / "sharded_train.py"
+    script.write_text(SHARDED_TRAIN_SCRIPT)
+    env = dict(os.environ,
+               PYTHONPATH=str(pathlib.Path(__file__).resolve().parents[1] / "src"))
+    out = subprocess.run([sys.executable, str(script), str(tmp_path)],
+                         env=env, capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    one, mesh = res["one"], res["mesh"]
+    total = sum(one["bytes"])
+    assert one["bytes"][0] == total              # unsharded: all on device 0
+    # a quarter each, plus the few small leaves every device replicates
+    assert all(0.25 <= b / total <= 0.3 for b in mesh["bytes"]), mesh["bytes"]
+    for (s1, l1), (s2, l2) in zip(one["losses"], mesh["losses"]):
+        assert s1 == s2 and abs(l1 - l2) < 1e-3 * abs(l1), res
