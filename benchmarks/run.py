@@ -16,7 +16,6 @@
   bench_kernels           kernel-tuning gate: the repo's own Pallas kernels
                           (attention/SSD) tuned through TuningSession —
                           tuned must beat the block=512 serving default
-  bench_roofline          §Roofline table from the 80-cell dry-run records
 
 Prints a final ``name,us_per_call,derived`` CSV.  Run with
 ``PYTHONPATH=src python -m benchmarks.run``.  Flags:
@@ -184,8 +183,8 @@ def main(argv=None) -> None:
                    bench_autotune, bench_beyond_transforms, bench_eval_cache,
                    bench_faults, bench_fleet, bench_kernels,
                    bench_mcts_vs_greedy, bench_pragma_stacking,
-                   bench_roofline, bench_session, bench_store,
-                   bench_surrogate, bench_warm_start)
+                   bench_session, bench_store, bench_surrogate,
+                   bench_warm_start)
 
     suites = {
         "pragma_stacking": bench_pragma_stacking.main,
@@ -202,7 +201,6 @@ def main(argv=None) -> None:
         "fleet": bench_fleet.main,
         "beyond_transforms": bench_beyond_transforms.main,
         "kernels": bench_kernels.main,
-        "roofline": bench_roofline.main,
         "analysis": bench_analysis.main,
     }
     if args.quick:

@@ -1,15 +1,18 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Initialises (or restores) parameters, builds the engine, and runs a wave of
-synthetic requests — the ``serve_step`` counterpart of launch.train.
+synthetic requests — the ``serve_step`` counterpart of launch.train.  It
+records the engine's spans (``repro.spans``) over the wave and prints, for
+each span name, the count and the total host time, and the host syncs per
+decode step.  The wave's first call compiles, so these times are not a
+serving speed: ``bench/`` measures that.
 
 ``--tuned-schedules`` closes the autotuning loop: it takes the
 ``kernel_schedules.json`` written by ``benchmarks/bench_kernels.py`` (the
 winning block sizes of a :class:`~repro.core.kernelworkload.KernelWorkload`
 tuning run) and installs them into the :class:`~repro.configs.base.
 ModelConfig` serving knobs (``attn_q_chunk``, ``ssd_chunk``), so a tuned
-kernel schedule is measured as end-to-end tokens/sec rather than kernel
-microseconds.
+kernel schedule is what the model path serves with.
 """
 
 from __future__ import annotations
@@ -18,7 +21,6 @@ import argparse
 import dataclasses
 import json
 import logging
-import time
 
 _log = logging.getLogger("repro.launch.serve")
 
@@ -35,7 +37,7 @@ def apply_tuned_schedules(cfg, path):
     sweep may cover kernels a given serving config never installs — and
     rejecting the whole file over one stale row would silently throw away
     the tuned schedules that *do* apply.  The skips are loud (one warning
-    per entry) so the tokens/sec comparison is never quietly mis-scoped.
+    per entry) so a comparison is never quietly mis-scoped.
     """
     from repro.core.kernelworkload import serve_overrides
 
@@ -68,6 +70,25 @@ def apply_tuned_schedules(cfg, path):
     return dataclasses.replace(cfg, **overrides), overrides
 
 
+def span_summary(kept) -> list[str]:
+    """For each span name, its count and total host time, then the host
+    syncs (token and position pulls) per decode step."""
+    totals: dict[str, list] = {}
+    for sp in kept:
+        t = totals.setdefault(sp.name, [0, 0])
+        t[0] += 1
+        t[1] += sp.end_ns - sp.start_ns
+    lines = [f"{name}: {n} spans, {ns / 1e6:.3f} ms"
+             for name, (n, ns) in totals.items()]
+    steps = totals.get("serve.dispatch", [0])[0]
+    syncs = sum(sp.args.get("syncs", 0) for sp in kept
+                if sp.name == "serve.emit")
+    if steps:
+        lines.append(f"host syncs per decode step: {syncs / steps:.2f} "
+                     f"({syncs} over {steps} steps)")
+    return lines
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", type=str, default="internlm2_1_8b")
@@ -94,6 +115,7 @@ def main(argv=None):
     import jax
     import numpy as np
 
+    from repro import spans
     from repro.configs.base import get_config
     from repro.models.model import build_model
     from repro.serve.engine import Request, ServeEngine
@@ -123,12 +145,13 @@ def main(argv=None):
                                              args.prompt_len)),
                     max_new_tokens=args.max_new)
             for _ in range(args.requests)]
-    t0 = time.perf_counter()
-    out = eng.generate(reqs)
-    dt = time.perf_counter() - t0
+    spans.clear()
+    with spans.recording():
+        out = eng.generate(reqs)
     tok = sum(len(r.out) for r in out)
-    print(f"[launch.serve] {args.arch}: {tok} tokens / {len(reqs)} requests "
-          f"in {dt:.2f}s ({tok/dt:.1f} tok/s)")
+    print(f"[launch.serve] {args.arch}: {tok} tokens / {len(reqs)} requests")
+    for line in span_summary(spans.recorded()):
+        print(f"[launch.serve] {line}")
     for i, r in enumerate(out[:4]):
         print(f"  req{i}: {r.out[:10]}{'…' if len(r.out) > 10 else ''}")
 

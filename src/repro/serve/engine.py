@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.model import Model, build_model
+from repro.spans import span
 
 
 @dataclass
@@ -41,47 +42,70 @@ class ServeEngine:
         self._decode = jax.jit(self.model.decode_step, donate_argnums=(2,))
 
     def generate(self, requests: list[Request]) -> list[Request]:
-        """Greedy-decode a wave of requests (all admitted together)."""
+        """Greedy-decode a wave of requests (all admitted together).
+
+        Host spans (``repro.spans``): ``serve.generate`` holds the call;
+        in it ``serve.prefill`` runs to the first token's dispatch, then
+        the decode loop alternates ``serve.emit`` (the live slots' tokens
+        pulled to the host, the stop checks and the position pulled;
+        ``syncs`` counts those pulls) and ``serve.dispatch`` (one decode
+        step, its argmax and the position's increment)."""
         assert len(requests) <= self.max_batch
+        with span("serve.generate"):
+            self._generate(requests)
+        return requests
+
+    def _generate(self, requests: list[Request]) -> None:
         B = len(requests)
         # uniform-length prefill via right-align padding to the longest prompt
         plen = max(len(r.prompt) for r in requests)
-        toks = np.zeros((B, plen), np.int32)
-        for i, r in enumerate(requests):
-            toks[i, plen - len(r.prompt):] = r.prompt     # left-pad with 0
-        batch = {"tokens": jnp.asarray(toks)}
-        if self.cfg.family == "vlm":
-            batch["patches"] = jnp.zeros(
-                (B, self.cfg.num_patches, self.cfg.d_model), jnp.float32)
-        if self.cfg.family == "audio":
-            batch["frames"] = jnp.zeros(
-                (B, self.cfg.enc_seq, self.cfg.d_model), jnp.float32)
+        with span("serve.prefill"):
+            toks = np.zeros((B, plen), np.int32)
+            for i, r in enumerate(requests):
+                toks[i, plen - len(r.prompt):] = r.prompt     # left-pad with 0
+            batch = {"tokens": jnp.asarray(toks)}
+            if self.cfg.family == "vlm":
+                batch["patches"] = jnp.zeros(
+                    (B, self.cfg.num_patches, self.cfg.d_model), jnp.float32)
+            if self.cfg.family == "audio":
+                batch["frames"] = jnp.zeros(
+                    (B, self.cfg.enc_seq, self.cfg.d_model), jnp.float32)
 
-        logits, pre_caches = self.model.prefill(self.params, batch)
-        caches = self.model.init_caches(B, self.max_seq, filled=plen)
-        caches = _install_prefix(caches, pre_caches, self.max_seq)
+            logits, pre_caches = self.model.prefill(self.params, batch)
+            caches = self.model.init_caches(B, self.max_seq, filled=plen)
+            caches = _install_prefix(caches, pre_caches, self.max_seq)
 
-        pos = jnp.full((B,), plen, jnp.int32)
-        next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+            pos = jnp.full((B,), plen, jnp.int32)
+            next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
         live = np.ones((B,), bool)
         max_new = max(r.max_new_tokens for r in requests)
         for _ in range(max_new):
-            for i, r in enumerate(requests):
-                if live[i]:
-                    r.out.append(int(next_tok[i]))
-                    if (self.eos_id is not None and r.out[-1] == self.eos_id) \
-                            or len(r.out) >= r.max_new_tokens:
-                        live[i] = False
-                        r.done = True
-            if not live.any() or int(pos[0]) + 1 >= self.max_seq:
+            with span("serve.emit") as emit:
+                syncs = 0
+                for i, r in enumerate(requests):
+                    if live[i]:
+                        r.out.append(int(next_tok[i]))
+                        syncs += 1
+                        if (self.eos_id is not None
+                                and r.out[-1] == self.eos_id) \
+                                or len(r.out) >= r.max_new_tokens:
+                            live[i] = False
+                            r.done = True
+                stop = not live.any()
+                if not stop:
+                    syncs += 1
+                    stop = int(pos[0]) + 1 >= self.max_seq
+                emit.args["syncs"] = syncs
+            if stop:
                 break
-            logits, caches = self._decode(
-                self.params, next_tok[:, None], caches, pos)
-            next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-            pos = pos + 1
+            with span("serve.dispatch"):
+                logits, caches = self._decode(
+                    self.params, next_tok[:, None], caches, pos)
+                next_tok = jnp.argmax(logits[:, -1, :],
+                                      axis=-1).astype(jnp.int32)
+                pos = pos + 1
         for r in requests:
             r.done = True
-        return requests
 
 
 def _install_prefix(caches, pre_caches, max_seq):
