@@ -72,7 +72,9 @@ def apply_tuned_schedules(cfg, path):
 
 def span_summary(kept) -> list[str]:
     """For each span name, its count and total host time, then the host
-    syncs (token and position pulls) per decode step."""
+    syncs per decode step: the ``syncs`` of every ``serve.emit`` (one
+    transfer of a step's tokens; the position stays on the host) over the
+    ``serve.dispatch`` spans."""
     totals: dict[str, list] = {}
     for sp in kept:
         t = totals.setdefault(sp.name, [0, 0])

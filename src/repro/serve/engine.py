@@ -44,12 +44,18 @@ class ServeEngine:
     def generate(self, requests: list[Request]) -> list[Request]:
         """Greedy-decode a wave of requests (all admitted together).
 
+        A round is left-padded to one length, so every slot shares one
+        decode position; the host keeps it as an integer (the prompt
+        length plus the steps dispatched) for the ``max_seq`` stop, and
+        the device keeps its own ``pos`` as the decode step's input.
+
         Host spans (``repro.spans``): ``serve.generate`` holds the call;
         in it ``serve.prefill`` runs to the first token's dispatch, then
-        the decode loop alternates ``serve.emit`` (the live slots' tokens
-        pulled to the host, the stop checks and the position pulled;
-        ``syncs`` counts those pulls) and ``serve.dispatch`` (one decode
-        step, its argmax and the position's increment)."""
+        the decode loop alternates ``serve.emit`` (the step's tokens for
+        every slot moved to the host in one transfer, then the appends and
+        stop checks; ``syncs`` counts the transfers, 1) and
+        ``serve.dispatch`` (one decode step, its argmax, the start of its
+        tokens' copy to the host and the position's increment)."""
         assert len(requests) <= self.max_batch
         with span("serve.generate"):
             self._generate(requests)
@@ -77,25 +83,22 @@ class ServeEngine:
 
             pos = jnp.full((B,), plen, jnp.int32)
             next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+            next_tok.copy_to_host_async()
+        host_pos = plen
         live = np.ones((B,), bool)
         max_new = max(r.max_new_tokens for r in requests)
         for _ in range(max_new):
-            with span("serve.emit") as emit:
-                syncs = 0
+            with span("serve.emit", syncs=1):
+                toks = np.asarray(next_tok)     # the step's one transfer
                 for i, r in enumerate(requests):
                     if live[i]:
-                        r.out.append(int(next_tok[i]))
-                        syncs += 1
+                        r.out.append(int(toks[i]))
                         if (self.eos_id is not None
                                 and r.out[-1] == self.eos_id) \
                                 or len(r.out) >= r.max_new_tokens:
                             live[i] = False
                             r.done = True
-                stop = not live.any()
-                if not stop:
-                    syncs += 1
-                    stop = int(pos[0]) + 1 >= self.max_seq
-                emit.args["syncs"] = syncs
+                stop = not live.any() or host_pos + 1 >= self.max_seq
             if stop:
                 break
             with span("serve.dispatch"):
@@ -103,7 +106,9 @@ class ServeEngine:
                     self.params, next_tok[:, None], caches, pos)
                 next_tok = jnp.argmax(logits[:, -1, :],
                                       axis=-1).astype(jnp.int32)
+                next_tok.copy_to_host_async()
                 pos = pos + 1
+                host_pos += 1
         for r in requests:
             r.done = True
 

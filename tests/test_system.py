@@ -157,3 +157,96 @@ def test_sharded_training_creates_state_sharded(tmp_path):
     assert all(0.25 <= b / total <= 0.3 for b in mesh["bytes"]), mesh["bytes"]
     for (s1, l1), (s2, l2) in zip(one["losses"], mesh["losses"]):
         assert s1 == s2 and abs(l1 - l2) < 1e-3 * abs(l1), res
+
+
+def _greedy_per_slot(m, params, prompt, max_new, max_seq, eos_id=None):
+    """One request alone: prefill, then decode steps, pulling each token
+    and keeping the position as the engine's stop rules read it."""
+    from repro.serve.engine import _install_prefix
+
+    decode = jax.jit(m.decode_step)
+    logits, pre = m.prefill(params, {"tokens": jnp.asarray([prompt], jnp.int32)})
+    caches = _install_prefix(
+        m.init_caches(1, max_seq, filled=len(prompt)), pre, max_seq)
+    pos, out = len(prompt), []
+    while True:
+        out.append(int(jnp.argmax(logits[0, -1])))
+        if out[-1] == eos_id or len(out) >= max_new or pos + 1 >= max_seq:
+            return out
+        logits, caches = decode(params, jnp.asarray([[out[-1]]], jnp.int32),
+                                caches, jnp.asarray([pos], jnp.int32))
+        pos += 1
+
+
+@pytest.fixture(scope="module")
+def dense_model():
+    from repro.configs.base import get_config
+    from repro.models.model import build_model
+
+    cfg = get_config("internlm2_1_8b").reduced()
+    m = build_model(cfg)
+    return cfg, m, m.init(jax.random.key(3))
+
+
+_PROMPTS = [[3, 17, 9, 40], [8, 1, 22, 5], [60, 2, 2, 11]]
+
+
+@pytest.mark.parametrize("with_eos", [False, True])
+def test_serve_engine_matches_per_slot_greedy(dense_model, with_eos):
+    """Mixed max_new_tokens, with and without an EOS id: each request gets
+    exactly the tokens of a plain greedy loop over that request alone."""
+    from repro.serve.engine import Request, ServeEngine
+
+    cfg, m, params = dense_model
+    max_new, max_seq = [2, 5, 8], 64
+    eos_id = None
+    if with_eos:        # a token the longest request makes mid-way
+        eos_id = _greedy_per_slot(m, params, _PROMPTS[2], 8, max_seq)[3]
+    want = [_greedy_per_slot(m, params, p, n, max_seq, eos_id)
+            for p, n in zip(_PROMPTS, max_new)]
+    if with_eos:
+        assert len(want[2]) <= 4 and want[2][-1] == eos_id
+    eng = ServeEngine(cfg, params, max_batch=3, max_seq=max_seq, eos_id=eos_id)
+    got = eng.generate([Request(prompt=list(p), max_new_tokens=n)
+                        for p, n in zip(_PROMPTS, max_new)])
+    assert [r.out for r in got] == want
+    assert all(r.done for r in got)
+
+
+def test_serve_emit_makes_one_transfer_a_step(dense_model):
+    """Every serve.emit moves its step's tokens in one transfer, and the
+    emits frame the dispatches: one more emit than dispatches."""
+    from repro import spans
+    from repro.serve.engine import Request, ServeEngine
+
+    cfg, m, params = dense_model
+    eng = ServeEngine(cfg, params, max_batch=3, max_seq=64)
+    spans.clear()
+    with spans.recording():
+        eng.generate([Request(prompt=list(p), max_new_tokens=n)
+                      for p, n in zip(_PROMPTS, [2, 5, 8])])
+    kept = spans.recorded()
+    emits = [s for s in kept if s.name == "serve.emit"]
+    dispatches = [s for s in kept if s.name == "serve.dispatch"]
+    assert [s.args["syncs"] for s in emits] == [1] * len(emits)
+    assert len(dispatches) == 7 and len(emits) == len(dispatches) + 1
+    order = [s.name for s in sorted(emits + dispatches,
+                                    key=lambda s: s.start_ns)]
+    assert order == ["serve.emit", "serve.dispatch"] * 7 + ["serve.emit"]
+
+
+@pytest.mark.parametrize("plen", [60, 63])
+def test_serve_engine_stops_at_max_seq(dense_model, plen):
+    """The host-side position stops a round where the position pulled from
+    the device did: after max_seq - plen tokens, the last at max_seq - 1."""
+    from repro.serve.engine import Request, ServeEngine
+
+    cfg, m, params = dense_model
+    max_seq = 64
+    prompts = [[(7 * i + j) % 97 + 1 for j in range(plen)] for i in range(2)]
+    want = [_greedy_per_slot(m, params, p, 16, max_seq) for p in prompts]
+    assert [len(w) for w in want] == [max_seq - plen] * 2
+    eng = ServeEngine(cfg, params, max_batch=2, max_seq=max_seq)
+    got = eng.generate([Request(prompt=p, max_new_tokens=16) for p in prompts])
+    assert [r.out for r in got] == want
+    assert all(r.done for r in got)
