@@ -48,10 +48,16 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 128
     qkv_bias: bool = False
-    rope_theta: float = 10_000.0
+    rope_theta: float = 10_000.0    # 0: absolute sinusoidal positions
+    position_embedding: str = "rope"    # rope | nope (no positions at all)
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     act: str = "silu"               # mlp activation (gated unless act=="gelu")
+    # Granite-style scales; each is applied only where it is set
+    embedding_multiplier: float = 1.0   # embeddings multiplied by this
+    residual_multiplier: float = 1.0    # sublayer outputs, before the add
+    attention_multiplier: float = 0.0   # score scale (0: 1/sqrt(head_dim))
+    logits_scaling: float = 1.0         # logits divided by this
 
     # MoE
     n_experts: int = 0
@@ -59,6 +65,10 @@ class ModelConfig:
     top_k: int = 0
     n_dense_layers: int = 0         # leading dense layers (DeepSeek=3, Kimi=1)
     moe_d_ff: int = 0               # per-expert hidden (d_ff = dense-layer hidden)
+    shared_d_ff: int = 0            # shared-expert hidden (0: moe_d_ff ×
+                                    # n_shared_experts)
+    n_experts_held: int = 0         # experts 0..n-1 held on this chip, routed
+                                    # over all n_experts (0: all of them)
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.001
 
@@ -75,6 +85,10 @@ class ModelConfig:
     block_pattern: tuple[str, ...] = ()   # repeating unit, e.g. ("rec","rec","attn")
     lru_width: int = 0
     window: int = 0                 # local-attention window
+
+    # per-layer pattern (Granite 4.0-H): "mamba" | "attention" for each
+    # layer, every layer followed by the held-expert FFN
+    layer_types: tuple[str, ...] = ()
 
     # SSM (Mamba-2)
     ssm_state: int = 0
@@ -138,6 +152,14 @@ class ModelConfig:
         if self.n_experts:
             kw.update(n_experts=8, top_k=min(self.top_k, 2), moe_d_ff=64,
                       n_dense_layers=min(self.n_dense_layers, 1))
+            if self.shared_d_ff:
+                kw.update(shared_d_ff=96)
+        if self.layer_types:
+            # the shortest prefix that holds every kind of layer, and a
+            # share of the experts, as one chip of a deployment holds
+            n = 1 + max(self.layer_types.index(t) for t in set(self.layer_types))
+            kw.update(n_layers=n, layer_types=self.layer_types[:n],
+                      n_experts_held=3)
         if self.use_mla:
             kw.update(q_lora_rank=64, kv_lora_rank=32, qk_nope_dim=32,
                       qk_rope_dim=16, v_head_dim=32)
@@ -156,6 +178,7 @@ _REGISTRY = [
     "qwen1_5_32b", "internlm2_1_8b", "qwen1_5_110b", "glm4_9b",
     "kimi_k2_1t_a32b", "deepseek_v3_671b", "whisper_base",
     "phi_3_vision_4_2b", "recurrentgemma_2b", "mamba2_130m",
+    "granite_4_0_h_small",
 ]
 
 

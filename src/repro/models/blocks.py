@@ -8,11 +8,19 @@ Kinds:
   rec        RG-LRU recurrent block + GeGLU MLP           (recurrentgemma)
   lattn      local (sliding-window) MQA attention + MLP   (recurrentgemma)
   mamba      Mamba-2 SSD mixer                            (mamba2)
+  mamba_moe  Mamba-2 SSD mixer + held-expert FFN          (granite-4.0-h)
+  attn_moe   GQA attention (NoPE, scaled) + held-expert FFN (granite-4.0-h)
   enc        bidirectional attention + GELU MLP (LN+bias) (whisper encoder)
   dec        causal self-attn + cross-attn + GELU MLP     (whisper decoder)
 
-Every block returns ``(x, cache, aux)`` with aux = MoE load-balance loss (0.0
-elsewhere) so stacks can be scanned uniformly.
+The held-expert kinds route over all experts and compute the experts held
+here without dropping a token (``layers.expert_ffn``), and scale both
+sublayers' outputs by ``cfg.residual_multiplier`` before their residual add.
+
+Every block returns ``(x, cache, aux)`` so stacks can be scanned uniformly:
+aux = MoE load-balance loss for ``moe``/``mla_moe`` (0.0 elsewhere), and
+``{"expert_rows": n}`` for the held-expert kinds, the int32 count of
+(token, held expert) pairs their layer computed.
 """
 
 from __future__ import annotations
@@ -28,6 +36,7 @@ from .layers import (
     attn_axes,
     attn_params_init,
     dense_init,
+    expert_ffn,
     layernorm,
     mlp,
     mlp_axes,
@@ -80,6 +89,12 @@ def init_block(kind: str, key, cfg):
                 "ln2": _norm_init(cfg), "mlp": mlp_params_init(ks[1], cfg)}
     if kind == "mamba":
         return {"ln": _norm_init(cfg), "mixer": mamba_params_init(ks[0], cfg)}
+    if kind == "mamba_moe":
+        return {"ln1": _norm_init(cfg), "mixer": mamba_params_init(ks[0], cfg),
+                "ln2": _norm_init(cfg), "moe": moe_params_init(ks[1], cfg)}
+    if kind == "attn_moe":
+        return {"ln1": _norm_init(cfg), "attn": attn_params_init(ks[0], cfg),
+                "ln2": _norm_init(cfg), "moe": moe_params_init(ks[1], cfg)}
     if kind == "enc":
         return {"ln1": _norm_init(cfg, True), "attn": attn_params_init(ks[0], cfg),
                 "ln2": _norm_init(cfg, True), "mlp": mlp_params_init(ks[1], cfg)}
@@ -108,6 +123,12 @@ def block_axes(kind: str, cfg):
                 "ln2": _NORM_AXES, "mlp": mlp_axes(cfg)}
     if kind == "mamba":
         return {"ln": _NORM_AXES, "mixer": mamba_axes(cfg)}
+    if kind == "mamba_moe":
+        return {"ln1": _NORM_AXES, "mixer": mamba_axes(cfg),
+                "ln2": _NORM_AXES, "moe": moe_axes(cfg)}
+    if kind == "attn_moe":
+        return {"ln1": _NORM_AXES, "attn": attn_axes(cfg),
+                "ln2": _NORM_AXES, "moe": moe_axes(cfg)}
     if kind == "enc":
         return {"ln1": _NORM_AXES_B, "attn": attn_axes(cfg),
                 "ln2": _NORM_AXES_B, "mlp": mlp_axes(cfg)}
@@ -123,7 +144,7 @@ def init_cache(kind: str, cfg, batch: int, s_max: int, enc_seq: int = 0):
     from .mamba2 import _dims
 
     cdt = jnp.dtype(cfg.dtype)
-    if kind in ("dense", "moe", "enc"):
+    if kind in ("dense", "moe", "enc", "attn_moe"):
         KV, hd = cfg.n_kv_heads, cfg.head_dim
         return KVCache(k=jnp.zeros((batch, s_max, KV, hd), cdt),
                        v=jnp.zeros((batch, s_max, KV, hd), cdt),
@@ -145,7 +166,7 @@ def init_cache(kind: str, cfg, batch: int, s_max: int, enc_seq: int = 0):
         return RecurrentCache(
             conv=jnp.zeros((batch, cfg.conv_kernel - 1, cfg.lru_width), cdt),
             h=jnp.zeros((batch, cfg.lru_width), jnp.float32))
-    if kind == "mamba":
+    if kind in ("mamba", "mamba_moe"):
         d_in, H, G, N, P, conv_ch = _dims(cfg)
         return SSMCache(
             conv=jnp.zeros((batch, cfg.conv_kernel - 1, conv_ch), cdt),
@@ -165,14 +186,14 @@ def init_cache(kind: str, cfg, batch: int, s_max: int, enc_seq: int = 0):
 def cache_axes(kind: str, cfg):
     """Logical sharding axes mirroring :func:`init_cache`'s structure."""
     kv = ("batch", "kv_seq", "kv_heads", None)
-    if kind in ("dense", "moe", "enc", "lattn"):
+    if kind in ("dense", "moe", "enc", "lattn", "attn_moe"):
         return KVCache(k=kv, v=kv, length=("batch",))
     if kind in ("mla_dense", "mla_moe"):
         return MLACache(ckv=("batch", "kv_seq", None),
                         krope=("batch", "kv_seq", None), length=("batch",))
     if kind == "rec":
         return RecurrentCache(conv=("batch", None, "lru"), h=("batch", "lru"))
-    if kind == "mamba":
+    if kind in ("mamba", "mamba_moe"):
         return SSMCache(conv=("batch", None, "ff"),
                         h=("batch", "ssm_heads", None, None))
     if kind == "dec":
@@ -250,6 +271,15 @@ def apply_block(kind: str, x, p, cfg, positions, cache=None):
     if kind == "mamba":
         h, c = mamba_block(_norm(x, p["ln"], cfg), p["mixer"], cfg, cache=cache)
         return x + h, c, zero
+    if kind in ("mamba_moe", "attn_moe"):
+        xn = _norm(x, p["ln1"], cfg)
+        if kind == "mamba_moe":
+            h, c = mamba_block(xn, p["mixer"], cfg, cache=cache)
+        else:
+            h, c = attention(xn, p["attn"], cfg, positions, cache=cache)
+        x = x + h * cfg.residual_multiplier
+        y, rows = expert_ffn(_norm(x, p["ln2"], cfg), p["moe"], cfg)
+        return x + y * cfg.residual_multiplier, c, {"expert_rows": rows}
     if kind == "enc":
         # bidirectional self-attention (no mask, no rope — whisper uses
         # absolute sinusoidal positions added at the embedding)

@@ -1,5 +1,6 @@
 """Shared layer library: norms, RoPE, GQA/local attention with KV caches,
-gated MLPs, and the expert-parallel MoE block.
+gated MLPs, the expert-parallel MoE block, and the drop-free expert layer
+for the experts one chip holds.
 
 All layers are pure functions over explicit parameter pytrees (no framework),
 cast activations to ``cfg.dtype`` and keep master params in ``cfg.param_dtype``.
@@ -124,8 +125,9 @@ def _qkv(x, p, cfg):
     return q, k, v
 
 
-def _sdpa(q, k, v, mask):
-    """q: (B,Sq,H,hd), k/v: (B,Sk,KV,hd), mask bool broadcastable to (B,Sq,Sk).
+def _sdpa(q, k, v, mask, scale: float | None = None):
+    """q: (B,Sq,H,hd), k/v: (B,Sk,KV,hd), mask bool broadcastable to (B,Sq,Sk);
+    scores scaled by ``scale`` (default 1/sqrt(hd)).
 
     Scores/probs stay in the compute dtype (bf16 in production configs) with
     f32 row statistics and f32 PV accumulation — the XLA analogue of a flash
@@ -134,7 +136,8 @@ def _sdpa(q, k, v, mask):
     B, Sq, H, hd = q.shape
     KV = k.shape[2]
     g = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     qs = (q * jnp.asarray(scale, q.dtype)).reshape(B, Sq, KV, g, hd)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qs, k)            # compute dtype
     m = jnp.broadcast_to(mask, (B,) + mask.shape[1:])
@@ -154,11 +157,14 @@ def _sdpa(q, k, v, mask):
 def attention(x, p, cfg, positions, *, window: int = 0, cache: KVCache | None = None):
     """Returns (y, new_cache).  Train/prefill: cache=None builds causal (or
     windowed) self-attention and returns the fresh cache for serving.  Decode:
-    S==1 step appended to the cache."""
+    S==1 step appended to the cache.  ``cfg.position_embedding`` "nope"
+    applies no positions; ``cfg.attention_multiplier`` scales the scores."""
     B, S, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    scale = cfg.attention_multiplier or None
     q, k, v = _qkv(x, p, cfg)
-    if cfg.rope_theta:       # rope_theta=0 → absolute positions (whisper)
+    # rope_theta=0 → absolute positions at the embedding (whisper)
+    if cfg.rope_theta and cfg.position_embedding == "rope":
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
     # Shard attention by Q heads.  When kv heads don't cover the model axis
@@ -186,7 +192,7 @@ def attention(x, p, cfg, positions, *, window: int = 0, cache: KVCache | None = 
                 mask = kposb <= posb[:, :, None]
                 if window:
                     mask = mask & (kposb > posb[:, :, None] - window)
-                outs.append(_sdpa(qb, kb, vb, mask))
+                outs.append(_sdpa(qb, kb, vb, mask, scale))
             y = jnp.concatenate(outs, axis=1)
         else:
             qpos = positions[:, :, None]              # (B,S,1)
@@ -194,7 +200,7 @@ def attention(x, p, cfg, positions, *, window: int = 0, cache: KVCache | None = 
             mask = kpos <= qpos
             if window:
                 mask = mask & (kpos > qpos - window)
-            y = _sdpa(q, k, v, mask)
+            y = _sdpa(q, k, v, mask, scale)
         new_cache = KVCache(k=k, v=v, length=jnp.full((B,), S, jnp.int32))
     else:
         # decode: append this step, attend over valid prefix
@@ -210,7 +216,7 @@ def attention(x, p, cfg, positions, *, window: int = 0, cache: KVCache | None = 
         valid = kpos <= idx
         if window:
             valid = valid & (kpos > idx - window)
-        y = _sdpa(q, kc, vc, valid)
+        y = _sdpa(q, kc, vc, valid, scale)
         new_cache = KVCache(k=kc, v=vc, length=cache.length + S)
 
     y = y.reshape(B, S, H * hd)
@@ -263,21 +269,24 @@ def mlp(x, p, cfg):
 
 
 def moe_params_init(key, cfg):
+    """The router scores all ``n_experts``; the stacks hold the experts
+    held here (``n_experts_held``, all when unset)."""
     dt = jnp.dtype(cfg.param_dtype)
     edt = jnp.dtype(cfg.expert_dtype) if cfg.expert_dtype else dt
     d, fm, E = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    held = cfg.n_experts_held or E
     ks = jax.random.split(key, 5)
     p = {
         "router": dense_init(ks[0], (d, E), dt, scale=0.02),
         "experts": {
-            "gate": dense_init(ks[1], (E, d, fm), edt),
-            "up": dense_init(ks[2], (E, d, fm), edt),
-            "down": dense_init(ks[3], (E, fm, d), edt,
+            "gate": dense_init(ks[1], (held, d, fm), edt),
+            "up": dense_init(ks[2], (held, d, fm), edt),
+            "down": dense_init(ks[3], (held, fm, d), edt,
                                scale=1.0 / math.sqrt(fm)),
         },
     }
     if cfg.n_shared_experts:
-        fs = cfg.moe_d_ff * cfg.n_shared_experts
+        fs = cfg.shared_d_ff or cfg.moe_d_ff * cfg.n_shared_experts
         kss = jax.random.split(ks[4], 3)
         p["shared"] = {"gate": dense_init(kss[0], (d, fs), dt),
                        "up": dense_init(kss[1], (d, fs), dt),
@@ -440,3 +449,53 @@ def moe_ffn(x, p, cfg):
         h = sh.constrain(h, "batch", "seq", "ff")
         y = y + h @ sp["down"].astype(dt)
     return sh.constrain(y, "batch", "seq", "embed"), aux
+
+
+# ---------------------------------------------------------------------------
+# The experts one chip holds: routed over all, computed for its own, no drops
+# ---------------------------------------------------------------------------
+
+
+def routed_share(x2d, router_w, we, top_k: int, first: int = 0):
+    """The part of an MoE layer's routed output that the experts held here
+    give, for every token routed to them.
+
+    x2d: (T, D).  ``router_w`` (D, E) scores every expert of the layer;
+    ``we`` holds experts ``first .. first + E_h - 1`` (E_h on dim 0).  Each
+    token keeps its top ``top_k`` experts, weighted by a softmax over the
+    chosen logits (the same weights as renormalising a softmax over all E).
+    There is no capacity: a held expert computes every token that picked it.
+    The product runs over the held experts, gated by the routing weights
+    (zero where a token did not pick the expert).  At a decode step's few
+    tokens each held expert's weights are read whatever the routing, and
+    this needs no sort and no shapes that depend on the routing; a prefill
+    computes E_h rows a token where ``top_k`` · E_h / E are routed.
+
+    Returns (y (T, D), rows): ``rows`` counts the (token, held expert)
+    pairs the routing picked, as int32.
+    """
+    E_h = we["gate"].shape[0]
+    dt = x2d.dtype
+    logits = (x2d @ router_w.astype(dt)).astype(jnp.float32)          # (T,E)
+    top_l, top_i = jax.lax.top_k(logits, top_k)
+    w = jax.nn.softmax(top_l, axis=-1)                                 # (T,k)
+    idx = top_i - first
+    held = (idx >= 0) & (idx < E_h)
+    gate = jnp.einsum("tk,tke->te", w,
+                      jax.nn.one_hot(idx, E_h, dtype=jnp.float32))     # (T,E_h)
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", x2d, we["gate"].astype(dt)))
+    h = h * jnp.einsum("td,edf->tef", x2d, we["up"].astype(dt))
+    h = h * gate.astype(dt)[..., None]
+    y = jnp.einsum("tef,efd->td", h, we["down"].astype(dt))
+    return y, jnp.sum(held, dtype=jnp.int32)
+
+
+def expert_ffn(x, p, cfg):
+    """x: (B,S,D) → (y, rows): the held experts' routed part plus the
+    shared MLP, which every chip computes whole.  On one chip there is no
+    exchange: what absent experts would add is left out."""
+    B, S, D = x.shape
+    y, rows = routed_share(x.reshape(B * S, D), p["router"], p["experts"],
+                           cfg.top_k)
+    y = y.reshape(B, S, D) + mlp(x, p["shared"], cfg)
+    return sh.constrain(y, "batch", "seq", "embed"), rows

@@ -9,11 +9,14 @@ One uniform interface for the launcher, trainer, server and dry-run:
     logits, caches = m.prefill(params, batch)
     caches = m.init_caches(B, S_max, filled=S)  # serving state
     logits, caches = m.decode_step(params, tokens, caches, pos)
+    # with a share of the experts held here (m.expert_slots > 0):
+    logits, caches, expert_rows = m.decode_step(params, tokens, caches, pos)
 
 Layer stacks are grouped into homogeneous runs; each run of length >1 is
 ``lax.scan``-ned when ``cfg.scan_layers`` (compile time stays flat in depth)
 with optional ``jax.checkpoint`` rematerialisation.  Heterogeneous patterns
-(RecurrentGemma's rec-rec-attn) scan over the repeating *period*.
+(RecurrentGemma's rec-rec-attn, Granite's per-layer ``layer_types``) scan
+over the repeating *period*.
 """
 
 from __future__ import annotations
@@ -35,9 +38,29 @@ from .layers import dense_init, layernorm, rmsnorm
 Pytree = Any
 
 
+#: block kind of each of ``cfg.layer_types``' layer types
+LAYER_TYPE_KINDS = {"mamba": "mamba_moe", "attention": "attn_moe"}
+
+
+def _shortest_period(kinds: tuple[str, ...]) -> list[tuple[tuple[str, ...], int]]:
+    """[(period, repeat)] for the shortest period that tiles ``kinds``."""
+    n = len(kinds)
+    p = next(p for p in range(1, n + 1) if n % p == 0
+             and all(kinds[i] == kinds[i % p] for i in range(n)))
+    return [(kinds[:p], n // p)]
+
+
 def layer_groups(cfg: ModelConfig) -> list[tuple[tuple[str, ...], int]]:
     """List of (period_kinds, repeat).  A period of one kind is the common
-    case; RecurrentGemma uses the repeating period ("rec","rec","lattn")."""
+    case; RecurrentGemma uses the repeating period ("rec","rec","lattn");
+    a per-layer ``layer_types`` pattern is grouped into its shortest
+    period (Granite 4.0-H: nine Mamba-2 layers and one attention layer)."""
+    if cfg.layer_types:
+        if len(cfg.layer_types) < cfg.n_layers:
+            raise ValueError(f"{len(cfg.layer_types)} layer_types for "
+                             f"{cfg.n_layers} layers")
+        return _shortest_period(tuple(
+            LAYER_TYPE_KINDS[t] for t in cfg.layer_types[:cfg.n_layers]))
     if cfg.family == "ssm":
         return [(("mamba",), cfg.n_layers)]
     if cfg.family == "hybrid":
@@ -69,8 +92,11 @@ class Model:
     loss: Callable          # (params, batch) -> (loss, aux)
     prefill: Callable       # (params, batch) -> (last logits, caches)
     decode_step: Callable   # (params, tokens(B,1), caches, pos(B,)) -> (logits, caches)
+                            # [, expert_rows] where expert_slots
     init_caches: Callable   # (batch, s_max, filled=0) -> caches
     cache_axes: Callable    # () -> logical axes tree mirroring init_caches
+    expert_slots: int = 0   # layers × experts held, where fewer are held
+                            # than routed: decode_step also returns rows
 
 
 def _sinusoidal(positions, d):
@@ -89,6 +115,10 @@ def build_model(cfg: ModelConfig) -> Model:
     groups = layer_groups(cfg)
     cdt = jnp.dtype(cfg.dtype)
     pdt = jnp.dtype(cfg.param_dtype)
+    share_layers = sum(reps * sum(k in LAYER_TYPE_KINDS.values() for k in period)
+                       for period, reps in groups)
+    held = cfg.n_experts_held
+    expert_slots = share_layers * held if 0 < held < cfg.n_experts else 0
 
     # ---------------------------------------------------------------- init --
 
@@ -172,25 +202,37 @@ def build_model(cfg: ModelConfig) -> Model:
             policy = jax.checkpoint_policies.checkpoint_dots
         return jax.checkpoint(fn, policy=policy)
 
+    def aux_zero():
+        z = {"router_loss": jnp.zeros((), jnp.float32)}
+        if share_layers:
+            z["expert_rows"] = jnp.zeros((), jnp.int32)
+        return z
+
+    def add_aux(total, a):
+        """``total`` plus a block's aux: a load-balance loss, or counts."""
+        a = a if isinstance(a, dict) else {"router_loss": a}
+        return {k: t + a[k] if k in a else t for k, t in total.items()}
+
     def run_stacks(x, stacks, positions, caches):
         """caches: list matching groups; entries may be None (no state needed,
         e.g. training without serving caches is handled by passing cross-KV
-        only for audio).  Returns (x, new_caches, aux_total)."""
-        aux_total = jnp.zeros((), jnp.float32)
+        only for audio).  Returns (x, new_caches, aux_total), aux_total a
+        dict: "router_loss", and "expert_rows" where layers hold experts."""
+        aux_total = aux_zero()
         new_caches = []
         for g, (period, reps) in enumerate(groups):
             sp = stacks[g]
             gc = caches[g] if caches is not None else None
 
             def period_fn(x, pp, pc, _period=period):
-                aux = jnp.zeros((), jnp.float32)
+                aux = aux_zero()
                 ncs = {}
                 for i, kind in enumerate(_period):
                     c = pc[f"b{i}"] if pc is not None else None
                     x, nc, a_ = apply_block(kind, x, pp[f"b{i}"], cfg,
                                             positions, cache=c)
                     ncs[f"b{i}"] = nc
-                    aux = aux + a_
+                    aux = add_aux(aux, a_)
                 return x, ncs, aux
 
             period_fn = _remat(period_fn)
@@ -198,20 +240,20 @@ def build_model(cfg: ModelConfig) -> Model:
             if reps == 1:
                 x, ncs, a_ = period_fn(x, sp, gc)
                 new_caches.append(ncs)
-                aux_total = aux_total + a_
+                aux_total = add_aux(aux_total, a_)
             elif cfg.scan_layers:
                 if gc is None:
                     def body(carry, pp):
                         x, aux = carry
                         x, ncs, a_ = period_fn(x, pp, None)
-                        return (x, aux + a_), ncs
+                        return (x, add_aux(aux, a_)), ncs
                     (x, aux_total), ncs = jax.lax.scan(body, (x, aux_total), sp)
                 else:
                     def body(carry, xs):
                         x, aux = carry
                         pp, pc = xs
                         x, ncs, a_ = period_fn(x, pp, pc)
-                        return (x, aux + a_), ncs
+                        return (x, add_aux(aux, a_)), ncs
                     (x, aux_total), ncs = jax.lax.scan(
                         body, (x, aux_total), (sp, gc))
                 new_caches.append(ncs)
@@ -223,7 +265,7 @@ def build_model(cfg: ModelConfig) -> Model:
                           if gc is not None else None)
                     x, ncs, a_ = period_fn(x, pp, pc)
                     ncs_list.append(ncs)
-                    aux_total = aux_total + a_
+                    aux_total = add_aux(aux_total, a_)
                 new_caches.append(_stack_pytrees(ncs_list))
         return x, new_caches, aux_total
 
@@ -231,6 +273,8 @@ def build_model(cfg: ModelConfig) -> Model:
 
     def embed_tokens(p, tokens, positions=None):
         x = jnp.take(p["embed"], tokens, axis=0).astype(cdt)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if not cfg.rope_theta:           # absolute sinusoidal positions
             if positions is None:
                 positions = jnp.arange(tokens.shape[1])[None, :]
@@ -241,6 +285,8 @@ def build_model(cfg: ModelConfig) -> Model:
         x = rmsnorm(x, p["final_norm"]["w"], cfg.norm_eps)
         head = p["embed"].T if cfg.tie_embeddings else p["head"]
         logits = x @ head.astype(cdt)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         return sh.constrain(logits, "batch", "seq", "vocab")
 
     def xent(logits, targets, mask=None):
@@ -317,6 +363,7 @@ def build_model(cfg: ModelConfig) -> Model:
     def loss(p, batch):
         x, positions, targets, mask, train_caches = build_inputs(p, batch)
         x, _, aux = run_stacks(x, p["stacks"], positions, train_caches)
+        aux = aux["router_loss"]
         logits = lm_logits(p, x)
         total = xent(logits, targets, mask) + cfg.router_aux_weight * aux
         if cfg.mtp:
@@ -393,19 +440,25 @@ def build_model(cfg: ModelConfig) -> Model:
         return lm_logits(p, x[:, -1:]), new_caches
 
     def decode_step(p, tokens, caches, pos):
-        """tokens: (B,1); pos: (B,) current position (== tokens generated)."""
-        B = tokens.shape[0]
+        """tokens: (B,1); pos: (B,) current position (== tokens generated).
+        Returns (logits, caches), and where ``expert_slots`` the int32
+        count of (token, held expert) rows the step's expert layers
+        computed."""
         x = jnp.take(p["embed"], tokens, axis=0).astype(cdt)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if not cfg.rope_theta:
             x = x + _sinusoidal(pos[:, None], cfg.d_model).astype(cdt)
         x = sh.constrain(x, "batch", "seq", "embed")
         positions = pos[:, None]
-        x, new_caches, _ = run_stacks(x, p["stacks"], positions, caches)
+        x, new_caches, aux = run_stacks(x, p["stacks"], positions, caches)
+        if expert_slots:
+            return lm_logits(p, x), new_caches, aux["expert_rows"]
         return lm_logits(p, x), new_caches
 
     return Model(cfg=cfg, init=init, axes=axes, loss=loss, prefill=prefill,
                  decode_step=decode_step, init_caches=init_caches,
-                 cache_axes=cache_axes)
+                 cache_axes=cache_axes, expert_slots=expert_slots)
 
 
 # ---------------------------------------------------------------------------

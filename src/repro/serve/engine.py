@@ -1,10 +1,13 @@
-"""Batched serving engine: continuous-batching-lite over prefill + decode.
+"""Batched serving engine: one wave of requests at a time over prefill +
+decode.
 
-The engine owns preallocated KV/state caches (``model.init_caches``) sized to
-``max_seq``, admits requests up to ``max_batch``, runs one jitted prefill per
-admission wave (left-padded into the shared cache) and steps all live
-sequences together with one jitted decode per token.  Slot recycling on EOS
-mimics continuous batching at the granularity this container can exercise.
+The engine takes up to ``max_batch`` requests as one wave, runs the model's
+prefill over their prompts (left-padded to one length), installs its caches
+into preallocated ones sized to ``max_seq`` (``model.init_caches``: KV
+caches, SSM states, or both side by side in one tree) and steps every slot
+of the wave together with one jitted decode step per token until the last
+request is done.  A slot whose request has finished keeps decoding until
+the wave ends; nothing is admitted between steps.
 """
 
 from __future__ import annotations
@@ -53,9 +56,13 @@ class ServeEngine:
         in it ``serve.prefill`` runs to the first token's dispatch, then
         the decode loop alternates ``serve.emit`` (the step's tokens for
         every slot moved to the host in one transfer, then the appends and
-        stop checks; ``syncs`` counts the transfers, 1) and
-        ``serve.dispatch`` (one decode step, its argmax, the start of its
-        tokens' copy to the host and the position's increment)."""
+        stop checks; ``syncs`` counts the waits, 1) and ``serve.dispatch``
+        (one decode step, its argmax, the start of its tokens' copy to the
+        host and the position's increment).  Where the model holds a share
+        of its experts (``model.expert_slots``), the step's count of
+        (token, held expert) rows is copied beside its tokens, and the emit
+        after a decode step records it as ``expert_rows``, with
+        ``expert_slots`` (expert layers × experts held)."""
         assert len(requests) <= self.max_batch
         with span("serve.generate"):
             self._generate(requests)
@@ -86,10 +93,14 @@ class ServeEngine:
             next_tok.copy_to_host_async()
         host_pos = plen
         live = np.ones((B,), bool)
+        rows = None             # the step's expert rows, where counted
         max_new = max(r.max_new_tokens for r in requests)
         for _ in range(max_new):
-            with span("serve.emit", syncs=1):
-                toks = np.asarray(next_tok)     # the step's one transfer
+            with span("serve.emit", syncs=1) as emit:
+                toks = np.asarray(next_tok)     # the step's one wait
+                if rows is not None:            # copied beside the tokens
+                    emit.args.update(expert_rows=int(rows),
+                                     expert_slots=self.model.expert_slots)
                 for i, r in enumerate(requests):
                     if live[i]:
                         r.out.append(int(toks[i]))
@@ -102,11 +113,14 @@ class ServeEngine:
             if stop:
                 break
             with span("serve.dispatch"):
-                logits, caches = self._decode(
+                logits, caches, *counted = self._decode(
                     self.params, next_tok[:, None], caches, pos)
                 next_tok = jnp.argmax(logits[:, -1, :],
                                       axis=-1).astype(jnp.int32)
                 next_tok.copy_to_host_async()
+                if counted:
+                    rows, = counted
+                    rows.copy_to_host_async()
                 pos = pos + 1
                 host_pos += 1
         for r in requests:

@@ -46,13 +46,14 @@ def test_smoke_train_and_serve(arch):
 
     caches = m.init_caches(B, S + 8, filled=S)
     tok = jnp.ones((B, 1), jnp.int32)
+    # [:2]: with a share of its experts held, a step also counts its rows
     dl, caches2 = m.decode_step(params, tok, caches,
-                                jnp.full((B,), S, jnp.int32))
+                                jnp.full((B,), S, jnp.int32))[:2]
     assert dl.shape == (B, 1, cfg.vocab_size)
     assert np.all(np.isfinite(np.asarray(dl, np.float32)))
     # decode twice: cache must advance without shape drift
-    dl2, _ = m.decode_step(params, tok, caches2,
-                           jnp.full((B,), S + 1, jnp.int32))
+    dl2 = m.decode_step(params, tok, caches2,
+                        jnp.full((B,), S + 1, jnp.int32))[0]
     assert dl2.shape == dl.shape
 
 
@@ -71,6 +72,13 @@ def test_full_config_faithful(arch):
         "phi_3_vision_4_2b": (32, 3072, 32, 32, 8192, 32064),
         "recurrentgemma_2b": (26, 2560, 10, 1, 7680, 256000),
         "mamba2_130m": (24, 768, 0, 0, 0, 50280),
+        "granite_4_0_h_small": (40, 4096, 32, 8, 0, 100352),
+    }
+    # expert width, experts per token, experts
+    experts = {
+        "kimi_k2_1t_a32b": (2048, 8, 384),
+        "deepseek_v3_671b": (2048, 8, 256),
+        "granite_4_0_h_small": (768, 10, 72),
     }
     L, d, h, kv, ff, v = table[arch]
     assert cfg.n_layers == L and cfg.d_model == d
@@ -78,10 +86,9 @@ def test_full_config_faithful(arch):
     if ff is not None:
         assert cfg.d_ff == ff
     assert cfg.vocab_size == v
+    assert (arch in experts) == bool(cfg.n_experts)
     if cfg.n_experts:
-        assert cfg.moe_d_ff == 2048
-        assert cfg.top_k == 8
-        assert cfg.n_experts in (384, 256)
+        assert (cfg.moe_d_ff, cfg.top_k, cfg.n_experts) == experts[arch]
 
 
 def test_param_counts_sane():
@@ -95,6 +102,7 @@ def test_param_counts_sane():
         "kimi_k2_1t_a32b": (0.95e12, 1.15e12),
         "whisper_base": (5e7, 1.2e8),
         "mamba2_130m": (1.0e8, 1.9e8),
+        "granite_4_0_h_small": (30e9, 34e9),
     }
     for arch, (lo, hi) in expect.items():
         n = count_params_from_specs(get_config(arch))
