@@ -37,6 +37,7 @@ from .layers import (
     attn_params_init,
     dense_init,
     expert_ffn,
+    layer_view,
     layernorm,
     mlp,
     mlp_axes,
@@ -235,12 +236,14 @@ def cross_kv(x_enc, p, cfg):
     return k.reshape(B, S, KV, hd), v.reshape(B, S, KV, hd)
 
 
-def apply_block(kind: str, x, p, cfg, positions, cache=None):
-    """Returns (x_out, new_cache, aux_loss)."""
+def apply_block(kind: str, x, p, cfg, positions, cache=None, layer=None):
+    """Returns (x_out, new_cache, aux_loss).  With ``layer``, ``cache``
+    stacks a scanned group's caches and the block updates entry ``layer``
+    (``layers.layer_view``): a decode step's rows and states, in place."""
     zero = jnp.zeros((), jnp.float32)
     if kind in ("dense", "moe"):
         h, c = attention(_norm(x, p["ln1"], cfg), p["attn"], cfg, positions,
-                         cache=cache)
+                         cache=cache, layer=layer)
         x = x + h
         if kind == "dense":
             x = x + mlp(_norm(x, p["ln2"], cfg), p["mlp"], cfg)
@@ -249,7 +252,7 @@ def apply_block(kind: str, x, p, cfg, positions, cache=None):
         return x + y, c, aux
     if kind in ("mla_dense", "mla_moe"):
         h, c = mla_attention(_norm(x, p["ln1"], cfg), p["attn"], cfg, positions,
-                             cache=cache)
+                             cache=cache, layer=layer)
         x = x + h
         if kind == "mla_dense":
             x = x + mlp(_norm(x, p["ln2"], cfg), p["mlp"], cfg)
@@ -258,25 +261,27 @@ def apply_block(kind: str, x, p, cfg, positions, cache=None):
         return x + y, c, aux
     if kind == "lattn":
         h, c = attention(_norm(x, p["ln1"], cfg), p["attn"], cfg, positions,
-                         window=cfg.window, cache=cache)
+                         window=cfg.window, cache=cache, layer=layer)
         x = x + h
         x = x + mlp(_norm(x, p["ln2"], cfg), p["mlp"], cfg)
         return x, c, zero
     if kind == "rec":
         h, c = recurrent_block(_norm(x, p["ln1"], cfg), p["rec"], cfg,
-                               cache=cache)
+                               cache=cache, layer=layer)
         x = x + h
         x = x + mlp(_norm(x, p["ln2"], cfg), p["mlp"], cfg)
         return x, c, zero
     if kind == "mamba":
-        h, c = mamba_block(_norm(x, p["ln"], cfg), p["mixer"], cfg, cache=cache)
+        h, c = mamba_block(_norm(x, p["ln"], cfg), p["mixer"], cfg,
+                           cache=cache, layer=layer)
         return x + h, c, zero
     if kind in ("mamba_moe", "attn_moe"):
         xn = _norm(x, p["ln1"], cfg)
         if kind == "mamba_moe":
-            h, c = mamba_block(xn, p["mixer"], cfg, cache=cache)
+            h, c = mamba_block(xn, p["mixer"], cfg, cache=cache, layer=layer)
         else:
-            h, c = attention(xn, p["attn"], cfg, positions, cache=cache)
+            h, c = attention(xn, p["attn"], cfg, positions, cache=cache,
+                             layer=layer)
         x = x + h * cfg.residual_multiplier
         y, rows = expert_ffn(_norm(x, p["ln2"], cfg), p["moe"], cfg)
         return x + y * cfg.residual_multiplier, c, {"expert_rows": rows}
@@ -296,11 +301,13 @@ def apply_block(kind: str, x, p, cfg, positions, cache=None):
     if kind == "dec":
         sc = cache.get("self") if cache is not None else None
         h, new_self = attention(_norm(x, p["ln1"], cfg), p["attn"], cfg,
-                                positions, cache=sc)
+                                positions, cache=sc, layer=layer)
         x = x + h
-        ck, cv = cache["cross_k"], cache["cross_v"]
+        # the encoder's K/V are read, never written
+        ck, cv = layer_view((cache["cross_k"], cache["cross_v"]), layer)
         x = x + _cross_attention(_norm(x, p["lnx"], cfg), p["xattn"], cfg, ck, cv)
         x = x + mlp(_norm(x, p["ln2"], cfg), p["mlp"], cfg)
-        new_cache = {"self": new_self, "cross_k": ck, "cross_v": cv}
+        new_cache = {"self": new_self, "cross_k": cache["cross_k"],
+                     "cross_v": cache["cross_v"]}
         return x, new_cache, zero
     raise KeyError(kind)

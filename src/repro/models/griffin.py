@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from . import sharding as sh
-from .layers import dense_init
+from .layers import dense_init, layer_view, layer_write
 
 _C = 8.0          # Griffin's fixed gate sharpness
 
@@ -86,15 +86,19 @@ def _rg_lru_scan(x, r, i, lam):
     return h, a, gated
 
 
-def recurrent_block(x, p, cfg, *, cache: RecurrentCache | None = None):
-    """Returns (y, new_cache).  x: (B,S,D)."""
+def recurrent_block(x, p, cfg, *, cache: RecurrentCache | None = None,
+                    layer=None):
+    """Returns (y, new_cache).  x: (B,S,D).  With ``layer``, ``cache``
+    stacks every layer's state and the step writes entry ``layer``'s
+    (``layers.layer_view``)."""
     B, S, D = x.shape
     dt = x.dtype
     gate = jax.nn.gelu(x @ p["in_gate"].astype(dt))
     xb = x @ p["in_x"].astype(dt)
     xb = sh.constrain(xb, "batch", "seq", "lru")
 
-    conv_state = cache.conv if cache is not None else None
+    state = layer_view(cache, layer)
+    conv_state = state.conv if state is not None else None
     xb, new_conv = _causal_conv(xb, p["conv_w"], p["conv_b"], conv_state)
 
     r = jax.nn.sigmoid((xb @ p["wr"].astype(dt) + p["br"].astype(dt))
@@ -110,11 +114,11 @@ def recurrent_block(x, p, cfg, *, cache: RecurrentCache | None = None):
         a = jnp.exp(log_a)
         b = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-12)) * (
             i[:, 0] * xb[:, 0].astype(jnp.float32))
-        new_h = a * cache.h.astype(jnp.float32) + b
+        new_h = a * state.h.astype(jnp.float32) + b
         h = new_h[:, None, :]
 
     y = (h.astype(dt) * gate) @ p["out"].astype(dt)
     y = sh.constrain(y, "batch", "seq", "embed")
     new_cache = RecurrentCache(conv=new_conv.astype(x.dtype),
                                h=new_h.astype(jnp.float32))
-    return y, new_cache
+    return y, layer_write(cache, new_cache, layer)

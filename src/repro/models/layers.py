@@ -83,6 +83,51 @@ class KVCache(NamedTuple):
     length: jax.Array       # (B,) — filled positions
 
 
+# A decode step over a scanned stack of layers passes each block its
+# group's caches whole, every leaf stacking the layers' on axis 0, with the
+# index ``layer`` of its own.  The block reads its entry and writes back only
+# what the step changes, so a donated stack is updated in place.  With
+# ``layer`` None a leaf is the block's own.
+
+
+def layer_view(buf, layer=None):
+    """A layer's cache (any pytree): ``buf``, or entry ``layer`` of each of
+    its stacked leaves."""
+    if layer is None:
+        return buf
+    return jax.tree.map(
+        lambda b: jax.lax.dynamic_index_in_dim(b, layer, keepdims=False), buf)
+
+
+def layer_write(buf, new, layer=None):
+    """``buf`` with a layer's cache set to ``new``, a pytree alike: ``new``
+    itself, or each stacked leaf with ``new``'s written whole at entry
+    ``layer``."""
+    if layer is None:
+        return new
+    return jax.tree.map(
+        lambda b, n: jax.lax.dynamic_update_index_in_dim(
+            b, n.astype(b.dtype), layer, 0), buf, new)
+
+
+def append_rows(buf, rows, idx, layer=None):
+    """(buf, view): ``rows`` (B, S, ...) written at position ``idx`` of a
+    layer's sequence cache, and that layer's cache holding them, to attend
+    over.  Into a stack only the rows are written, at entry ``layer``, and
+    the view is the entry as read before, with the rows put in: read back
+    after the write, the TPU compiler lays the whole stack out for the
+    attention's read and copies it whole into and out of the layer loop."""
+    rows = rows.astype(buf.dtype)
+    if layer is None:
+        buf = jax.lax.dynamic_update_slice_in_dim(buf, rows, idx, axis=1)
+        return buf, buf
+    view = jax.lax.dynamic_update_slice_in_dim(layer_view(buf, layer), rows,
+                                               idx, axis=1)
+    start = (layer, 0, idx) + (0,) * (buf.ndim - 3)
+    buf = jax.lax.dynamic_update_slice(buf, rows[None], start)
+    return buf, view
+
+
 def attn_params_init(key, cfg, d_model=None):
     d = d_model or cfg.d_model
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -154,11 +199,13 @@ def _sdpa(q, k, v, mask, scale: float | None = None):
     return o.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
-def attention(x, p, cfg, positions, *, window: int = 0, cache: KVCache | None = None):
+def attention(x, p, cfg, positions, *, window: int = 0,
+              cache: KVCache | None = None, layer=None):
     """Returns (y, new_cache).  Train/prefill: cache=None builds causal (or
     windowed) self-attention and returns the fresh cache for serving.  Decode:
-    S==1 step appended to the cache.  ``cfg.position_embedding`` "nope"
-    applies no positions; ``cfg.attention_multiplier`` scales the scores."""
+    S==1 step appended to the cache (to entry ``layer`` of stacked caches,
+    see ``layer_view``).  ``cfg.position_embedding`` "nope" applies no
+    positions; ``cfg.attention_multiplier`` scales the scores."""
     B, S, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     scale = cfg.attention_multiplier or None
@@ -204,11 +251,10 @@ def attention(x, p, cfg, positions, *, window: int = 0, cache: KVCache | None = 
         new_cache = KVCache(k=k, v=v, length=jnp.full((B,), S, jnp.int32))
     else:
         # decode: append this step, attend over valid prefix
-        idx = cache.length[0]                         # uniform fill pointer
-        kc = jax.lax.dynamic_update_slice_in_dim(
-            cache.k, k.astype(cache.k.dtype), idx, axis=1)
-        vc = jax.lax.dynamic_update_slice_in_dim(
-            cache.v, v.astype(cache.v.dtype), idx, axis=1)
+        length = layer_view(cache.length, layer)
+        idx = length[0]                               # uniform fill pointer
+        kbuf, kc = append_rows(cache.k, k, idx, layer)
+        vbuf, vc = append_rows(cache.v, v, idx, layer)
         kc = sh.constrain(kc, "batch", "kv_seq", "kv_heads", None)
         vc = sh.constrain(vc, "batch", "kv_seq", "kv_heads", None)
         Smax = kc.shape[1]
@@ -217,7 +263,8 @@ def attention(x, p, cfg, positions, *, window: int = 0, cache: KVCache | None = 
         if window:
             valid = valid & (kpos > idx - window)
         y = _sdpa(q, kc, vc, valid, scale)
-        new_cache = KVCache(k=kc, v=vc, length=cache.length + S)
+        new_cache = KVCache(k=kbuf, v=vbuf,
+                            length=layer_write(cache.length, length + S, layer))
 
     y = y.reshape(B, S, H * hd)
     y = y @ p["wo"].astype(y.dtype)

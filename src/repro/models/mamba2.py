@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from . import sharding as sh
-from .layers import dense_init, rmsnorm
+from .layers import dense_init, layer_view, layer_write, rmsnorm
 
 
 class SSMCache(NamedTuple):
@@ -104,8 +104,10 @@ def _ssd_chunked(x, dtv, a, b, c, chunk):
     return y, hT
 
 
-def mamba_block(x, p, cfg, *, cache: SSMCache | None = None):
-    """Returns (y, new_cache).  x: (B,S,D)."""
+def mamba_block(x, p, cfg, *, cache: SSMCache | None = None, layer=None):
+    """Returns (y, new_cache).  x: (B,S,D).  With ``layer``, ``cache``
+    stacks every layer's state and the step writes entry ``layer``'s
+    (``layers.layer_view``)."""
     from .griffin import _causal_conv
 
     B, S, D = x.shape
@@ -116,7 +118,8 @@ def mamba_block(x, p, cfg, *, cache: SSMCache | None = None):
     z, xbc, dt_raw = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * G * N], axis=-1)
     xbc = sh.constrain(xbc, "batch", "seq", None)
 
-    conv_state = cache.conv if cache is not None else None
+    state = layer_view(cache, layer)
+    conv_state = state.conv if state is not None else None
     xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_state)
     xbc = jax.nn.silu(xbc)
 
@@ -137,7 +140,7 @@ def mamba_block(x, p, cfg, *, cache: SSMCache | None = None):
         decay = jnp.exp(dtv[:, 0] * a[None, :])       # (B,H)
         upd = (dtv[:, 0, :, None] * xs[:, 0].astype(jnp.float32))[..., None] \
             * bg[:, :, None, :].astype(jnp.float32)   # (B,H,P,N)
-        hT = decay[..., None, None] * cache.h + upd
+        hT = decay[..., None, None] * state.h + upd
         y = jnp.einsum("bhpn,bhn->bhp", hT, cg.astype(jnp.float32))[:, None]
 
     y = y + xs.astype(jnp.float32) * p["d_skip"].astype(jnp.float32)[None, None, :, None]
@@ -147,4 +150,4 @@ def mamba_block(x, p, cfg, *, cache: SSMCache | None = None):
     out = y @ p["out_proj"].astype(dt_)
     out = sh.constrain(out, "batch", "seq", "embed")
     new_cache = SSMCache(conv=new_conv.astype(x.dtype), h=hT)
-    return out, new_cache
+    return out, layer_write(cache, new_cache, layer)

@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from . import sharding as sh
-from .layers import dense_init, rmsnorm, rope
+from .layers import (append_rows, dense_init, layer_view, layer_write,
+                     rmsnorm, rope)
 
 
 class MLACache(NamedTuple):
@@ -72,9 +73,12 @@ def _latents(x, p, cfg, positions):
     return q_nope, q_rope, ckv, k_rope
 
 
-def mla_attention(x, p, cfg, positions, *, cache: MLACache | None = None):
+def mla_attention(x, p, cfg, positions, *, cache: MLACache | None = None,
+                  layer=None):
     """Returns (y, new_cache).  Absorbed form throughout: scores are computed
-    in latent space, so train/prefill and decode share one code path."""
+    in latent space, so train/prefill and decode share one code path.  A
+    decode step with ``layer`` appends to that entry of stacked caches
+    (``layers.layer_view``)."""
     B, S, D = x.shape
     H = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -117,17 +121,17 @@ def mla_attention(x, p, cfg, positions, *, cache: MLACache | None = None):
         new_cache = MLACache(ckv=ckv, krope=k_rope,
                              length=jnp.full((B,), S, jnp.int32))
     else:
-        idx = cache.length[0]
-        keys_lat = jax.lax.dynamic_update_slice_in_dim(
-            cache.ckv, ckv.astype(cache.ckv.dtype), idx, axis=1)
-        keys_rope = jax.lax.dynamic_update_slice_in_dim(
-            cache.krope, k_rope.astype(cache.krope.dtype), idx, axis=1)
+        length = layer_view(cache.length, layer)
+        idx = length[0]
+        ckv_buf, keys_lat = append_rows(cache.ckv, ckv, idx, layer)
+        krope_buf, keys_rope = append_rows(cache.krope, k_rope, idx, layer)
         keys_lat = sh.constrain(keys_lat, "batch", "kv_seq", None)
         keys_rope = sh.constrain(keys_rope, "batch", "kv_seq", None)
         Smax = keys_lat.shape[1]
         mask = (jnp.arange(Smax)[None, None, :] <= idx)       # (1,1,Smax)
-        new_cache = MLACache(ckv=keys_lat, krope=keys_rope,
-                             length=cache.length + S)
+        new_cache = MLACache(
+            ckv=ckv_buf, krope=krope_buf,
+            length=layer_write(cache.length, length + S, layer))
 
     ctx_lat = _mla_scores_ctx(q_lat, q_rope, keys_lat, keys_rope, mask,
                               scale, dt)

@@ -16,7 +16,10 @@ Layer stacks are grouped into homogeneous runs; each run of length >1 is
 ``lax.scan``-ned when ``cfg.scan_layers`` (compile time stays flat in depth)
 with optional ``jax.checkpoint`` rematerialisation.  Heterogeneous patterns
 (RecurrentGemma's rec-rec-attn, Granite's per-layer ``layer_types``) scan
-over the repeating *period*.
+over the repeating *period*.  Training and prefill build a scanned group's
+caches as the scan's outputs; a decode step carries them through its scan
+and writes only what it changes into them (``m.inplace_layers`` counts the
+layers it so updates).
 """
 
 from __future__ import annotations
@@ -97,6 +100,8 @@ class Model:
     cache_axes: Callable    # () -> logical axes tree mirroring init_caches
     expert_slots: int = 0   # layers × experts held, where fewer are held
                             # than routed: decode_step also returns rows
+    inplace_layers: int = 0  # layers whose decode caches the step updates
+                             # inside their scanned group's stacked buffers
 
 
 def _sinusoidal(positions, d):
@@ -119,6 +124,12 @@ def build_model(cfg: ModelConfig) -> Model:
                        for period, reps in groups)
     held = cfg.n_experts_held
     expert_slots = share_layers * held if 0 < held < cfg.n_experts else 0
+
+    def scanned(reps):
+        return reps > 1 and cfg.scan_layers
+
+    inplace_layers = sum(reps * len(period) for period, reps in groups
+                         if scanned(reps))
 
     # ---------------------------------------------------------------- init --
 
@@ -213,60 +224,96 @@ def build_model(cfg: ModelConfig) -> Model:
         a = a if isinstance(a, dict) else {"router_loss": a}
         return {k: t + a[k] if k in a else t for k, t in total.items()}
 
+    def period_fn(period, positions):
+        """A period's blocks in turn: (x, params, caches[, layer]) ->
+        (x, new caches, aux)."""
+        def fn(x, pp, pc, layer=None):
+            aux = aux_zero()
+            ncs = {}
+            for i, kind in enumerate(period):
+                c = pc[f"b{i}"] if pc is not None else None
+                x, nc, a_ = apply_block(kind, x, pp[f"b{i}"], cfg, positions,
+                                        cache=c, layer=layer)
+                ncs[f"b{i}"] = nc
+                aux = add_aux(aux, a_)
+            return x, ncs, aux
+        return fn
+
+    def run_group(g, x, sp, gc, positions, aux_total):
+        """One group of ``run_stacks``: (x, new caches, aux_total)."""
+        period, reps = groups[g]
+        fn = _remat(period_fn(period, positions))
+        if reps == 1:
+            x, ncs, a_ = fn(x, sp, gc)
+            return x, ncs, add_aux(aux_total, a_)
+        if scanned(reps):
+            if gc is None:
+                def body(carry, pp):
+                    x, aux = carry
+                    x, ncs, a_ = fn(x, pp, None)
+                    return (x, add_aux(aux, a_)), ncs
+                (x, aux_total), ncs = jax.lax.scan(body, (x, aux_total), sp)
+            else:
+                def body(carry, xs):
+                    x, aux = carry
+                    pp, pc = xs
+                    x, ncs, a_ = fn(x, pp, pc)
+                    return (x, add_aux(aux, a_)), ncs
+                (x, aux_total), ncs = jax.lax.scan(
+                    body, (x, aux_total), (sp, gc))
+            return x, ncs, aux_total
+        ncs_list = []
+        for r in range(reps):
+            pp = jax.tree.map(lambda t: t[r], sp)
+            pc = jax.tree.map(lambda t: t[r], gc) if gc is not None else None
+            x, ncs, a_ = fn(x, pp, pc)
+            ncs_list.append(ncs)
+            aux_total = add_aux(aux_total, a_)
+        return x, _stack_pytrees(ncs_list), aux_total
+
     def run_stacks(x, stacks, positions, caches):
         """caches: list matching groups; entries may be None (no state needed,
         e.g. training without serving caches is handled by passing cross-KV
         only for audio).  Returns (x, new_caches, aux_total), aux_total a
-        dict: "router_loss", and "expert_rows" where layers hold experts."""
+        dict: "router_loss", and "expert_rows" where layers hold experts.
+        A scanned group takes its caches as the scan's inputs and builds
+        new ones as its outputs, which training's gradient needs."""
+        aux_total = aux_zero()
+        new_caches = []
+        for g in range(len(groups)):
+            gc = caches[g] if caches is not None else None
+            x, ncs, aux_total = run_group(g, x, stacks[g], gc, positions,
+                                          aux_total)
+            new_caches.append(ncs)
+        return x, new_caches, aux_total
+
+    def decode_stacks(x, stacks, positions, caches):
+        """Decode's layer loop, as ``run_stacks`` but for a scanned group:
+        it carries the group's stacked caches through the scan, and each
+        layer writes into them only what the step changes (a sequence
+        cache its new row, a state its new value, a read-only cache
+        nothing), so donated caches are updated in place and no cache is
+        built anew.  No gradient runs here, so nothing is rematerialised."""
         aux_total = aux_zero()
         new_caches = []
         for g, (period, reps) in enumerate(groups):
-            sp = stacks[g]
-            gc = caches[g] if caches is not None else None
+            if not scanned(reps):
+                x, gc, aux_total = run_group(g, x, stacks[g], caches[g],
+                                             positions, aux_total)
+                new_caches.append(gc)
+                continue
+            fn = period_fn(period, positions)
 
-            def period_fn(x, pp, pc, _period=period):
-                aux = aux_zero()
-                ncs = {}
-                for i, kind in enumerate(_period):
-                    c = pc[f"b{i}"] if pc is not None else None
-                    x, nc, a_ = apply_block(kind, x, pp[f"b{i}"], cfg,
-                                            positions, cache=c)
-                    ncs[f"b{i}"] = nc
-                    aux = add_aux(aux, a_)
-                return x, ncs, aux
+            def body(carry, xs, fn=fn):
+                x, aux, gc = carry
+                pp, layer = xs
+                x, gc, a_ = fn(x, pp, gc, layer)
+                return (x, add_aux(aux, a_), gc), None
 
-            period_fn = _remat(period_fn)
-
-            if reps == 1:
-                x, ncs, a_ = period_fn(x, sp, gc)
-                new_caches.append(ncs)
-                aux_total = add_aux(aux_total, a_)
-            elif cfg.scan_layers:
-                if gc is None:
-                    def body(carry, pp):
-                        x, aux = carry
-                        x, ncs, a_ = period_fn(x, pp, None)
-                        return (x, add_aux(aux, a_)), ncs
-                    (x, aux_total), ncs = jax.lax.scan(body, (x, aux_total), sp)
-                else:
-                    def body(carry, xs):
-                        x, aux = carry
-                        pp, pc = xs
-                        x, ncs, a_ = period_fn(x, pp, pc)
-                        return (x, add_aux(aux, a_)), ncs
-                    (x, aux_total), ncs = jax.lax.scan(
-                        body, (x, aux_total), (sp, gc))
-                new_caches.append(ncs)
-            else:
-                ncs_list = []
-                for r in range(reps):
-                    pp = jax.tree.map(lambda t: t[r], sp)
-                    pc = (jax.tree.map(lambda t: t[r], gc)
-                          if gc is not None else None)
-                    x, ncs, a_ = period_fn(x, pp, pc)
-                    ncs_list.append(ncs)
-                    aux_total = add_aux(aux_total, a_)
-                new_caches.append(_stack_pytrees(ncs_list))
+            (x, aux_total, gc), _ = jax.lax.scan(
+                body, (x, aux_total, caches[g]),
+                (stacks[g], jnp.arange(reps, dtype=jnp.int32)))
+            new_caches.append(gc)
         return x, new_caches, aux_total
 
     # ----------------------------------------------------- embedding / head --
@@ -451,14 +498,15 @@ def build_model(cfg: ModelConfig) -> Model:
             x = x + _sinusoidal(pos[:, None], cfg.d_model).astype(cdt)
         x = sh.constrain(x, "batch", "seq", "embed")
         positions = pos[:, None]
-        x, new_caches, aux = run_stacks(x, p["stacks"], positions, caches)
+        x, new_caches, aux = decode_stacks(x, p["stacks"], positions, caches)
         if expert_slots:
             return lm_logits(p, x), new_caches, aux["expert_rows"]
         return lm_logits(p, x), new_caches
 
     return Model(cfg=cfg, init=init, axes=axes, loss=loss, prefill=prefill,
                  decode_step=decode_step, init_caches=init_caches,
-                 cache_axes=cache_axes, expert_slots=expert_slots)
+                 cache_axes=cache_axes, expert_slots=expert_slots,
+                 inplace_layers=inplace_layers)
 
 
 # ---------------------------------------------------------------------------

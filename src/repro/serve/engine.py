@@ -62,9 +62,12 @@ class ServeEngine:
         of its experts (``model.expert_slots``), the step's count of
         (token, held expert) rows is copied beside its tokens, and the emit
         after a decode step records it as ``expert_rows``, with
-        ``expert_slots`` (expert layers × experts held)."""
+        ``expert_slots`` (expert layers × experts held).  ``serve.generate``
+        records ``inplace_layers``: the layers whose caches each decode step
+        updates in place inside their scanned group's stacked buffers."""
         assert len(requests) <= self.max_batch
-        with span("serve.generate"):
+        with span("serve.generate",
+                  inplace_layers=self.model.inplace_layers):
             self._generate(requests)
         return requests
 

@@ -1,4 +1,5 @@
-"""Compile the main path's Pallas kernels for a described TPU v5e.
+"""Compile the main path's Pallas kernels, and the serving decode step,
+for a described TPU v5e.
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described rather than attached, so these tests catch what interpret mode
@@ -10,16 +11,20 @@ may load the TPU library, and every test worker imports this file.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import get_config
 from repro.core import GEMM, Configuration, Tile, codegen
 from repro.kernels.attention import flash_attention
 from repro.kernels.ssd import ssd_scan
+from repro.models.model import build_model
 
 
 @pytest.fixture(scope="module")
@@ -100,3 +105,51 @@ def test_gemm_unaligned_tiles_are_refused(one_chip):
     fn = codegen.build_pallas(GEMM, cfg.apply(GEMM.nest()), interpret=False)
     with pytest.raises(Exception, match="(?i)divisible|align|tiling|shape"):
         jax.jit(fn).lower(_gemm_args(one_chip)).compile()
+
+
+def _hlo_ops(text):
+    """{name: (shape, opcode, operand names)} of a compiled module's text."""
+    ops = {}
+    for m in re.finditer(r"%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)",
+                         text):
+        ops[m.group(1)] = (m.group(2), m.group(3),
+                           re.findall(r"%(\S+?)(?:[,)]|$)", m.group(4)))
+    return ops
+
+
+# A decode step over 4 scanned layers at InternLM2-1.8B's and Mamba2-130M's
+# widths (bf16, batch 8, max_seq 512), its caches donated as the engine
+# donates them.  The TPU compiler picks the stacked caches' layout inside
+# the layer loop, which decides whether they are copied whole.
+@pytest.mark.parametrize("arch", ["internlm2_1_8b", "mamba2_130m"])
+def test_decode_step_updates_stacked_caches_in_place(one_chip, arch):
+    """No copy of a stacked cache, and into a stacked KV cache only the
+    step's row is written."""
+    cfg = dataclasses.replace(get_config(arch), n_layers=4, vocab_size=512)
+    m = build_model(cfg)
+    assert m.inplace_layers == 4
+
+    def s(t):
+        return jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(m.init, jax.random.key(0)))
+    caches = jax.eval_shape(lambda: m.init_caches(8, 512, filled=3))
+    text = jax.jit(m.decode_step, donate_argnums=(2,)).lower(
+        params, s(jax.ShapeDtypeStruct((8, 1), jnp.int32)),
+        jax.tree.map(s, caches),
+        s(jax.ShapeDtypeStruct((8,), jnp.int32))).compile().as_text()
+
+    leaves = jax.tree_util.tree_flatten_with_path(caches)[0]
+    stacked = {",".join(map(str, x.shape)) for _, x in leaves if x.ndim >= 3}
+    kv = {",".join(map(str, x.shape)) for path, x in leaves
+          if getattr(path[-1], "name", None) in ("k", "v")}
+    assert stacked and bool(kv) == (arch == "internlm2_1_8b")
+    ops = _hlo_ops(text)
+    copies = [n for n, (shape, op, _) in ops.items()
+              if op == "copy" and shape in stacked]
+    assert not copies, copies
+    writes = [args for shape, op, args in ops.values()
+              if op == "dynamic-update-slice" and shape in kv]
+    assert len(writes) == 2 * bool(kv)          # K and V
+    for args in writes:                         # (B, 1, KV, hd) into a layer
+        assert ops[args[1]][0].split(",")[2] == "1", ops[args[1]]

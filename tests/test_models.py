@@ -1,6 +1,8 @@
 """Per-architecture smoke tests: reduced config of the same family, one
 loss+grad step and one prefill+decode step on CPU, asserting shapes + finite."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -55,6 +57,62 @@ def test_smoke_train_and_serve(arch):
     dl2 = m.decode_step(params, tok, caches2,
                         jnp.full((B,), S + 1, jnp.int32))[0]
     assert dl2.shape == dl.shape
+
+
+@pytest.mark.parametrize("arch", arch_ids())
+def test_scanned_decode_matches_unscanned(arch):
+    """A decode step over scanned layers, which writes the step's rows and
+    states into its stacked caches in place, gives exactly what the
+    unscanned layers give: tokens, logits and every cache leaf (the rows no
+    step wrote, and Whisper's cross-attention K/V, included), after two
+    donated steps from one prefill."""
+    from repro.serve.engine import _install_prefix
+
+    base = get_config(arch).reduced()
+    # past a dense prefix of one layer, two MoE layers to scan
+    base = dataclasses.replace(base,
+                               n_layers=base.n_layers + base.n_dense_layers)
+    models = {scan: build_model(dataclasses.replace(base, scan_layers=scan))
+              for scan in (True, False)}
+    assert models[False].inplace_layers == 0
+    if base.layer_types:        # one unscanned period: nothing in place
+        assert models[True].inplace_layers == 0
+    else:
+        assert models[True].inplace_layers >= 2
+    params = models[False].init(jax.random.key(1))
+    batch = _batch(base)
+    batch["tokens"] = batch["tokens"][:, :S]
+    logits, pre = jax.jit(models[False].prefill)(params, batch)
+    filled = S + (base.num_patches if base.family == "vlm" else 0)
+    max_seq = filled + 8
+
+    out = {}
+    for scan, m in models.items():
+        caches = _install_prefix(m.init_caches(B, max_seq, filled=filled),
+                                 jax.tree.map(jnp.copy, pre), max_seq)
+        step = jax.jit(m.decode_step, donate_argnums=(2,))
+        tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+        seen = []
+        for i in range(2):
+            lg, caches = step(params, tok, caches,
+                              jnp.full((B,), filled + i, jnp.int32))[:2]
+            tok = jnp.argmax(lg[:, -1], axis=-1)[:, None].astype(jnp.int32)
+            seen.append((np.asarray(tok), np.asarray(lg)))
+        out[scan] = seen, jax.tree.map(np.asarray, caches)
+
+    (got, got_caches), (want, want_caches) = out[True], out[False]
+    for (t1, l1), (t2, l2) in zip(got, want):
+        np.testing.assert_array_equal(t1, t2)
+        np.testing.assert_array_equal(l1, l2)
+    assert (jax.tree.structure(got_caches)
+            == jax.tree.structure(want_caches))
+    for c1, c2 in zip(jax.tree.leaves(got_caches),
+                      jax.tree.leaves(want_caches)):
+        np.testing.assert_array_equal(c1, c2)
+    if base.family == "audio":          # read, never written
+        for name in ("cross_k", "cross_v"):
+            np.testing.assert_array_equal(got_caches[0]["b0"][name],
+                                          np.asarray(pre[0]["b0"][name]))
 
 
 @pytest.mark.parametrize("arch", arch_ids())
